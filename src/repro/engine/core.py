@@ -144,6 +144,11 @@ class EngineRequest:
     finish_time: Optional[float] = None
     tokens: List[int] = field(default_factory=list)
     token_times: List[float] = field(default_factory=list)
+    # logits behind tokens[0] and tokens[-1], kept only by an engine built
+    # with keep_logits=True (numerics checks across admission paths and
+    # devices; greedy streams of random-weight models cannot show them)
+    first_logits: Optional[np.ndarray] = None
+    last_logits: Optional[np.ndarray] = None
     slot: Optional[int] = None
     # new | running | swapped | preempted | handoff | done
     # ("handoff": prefill complete, KV pages in flight to a decode worker —
@@ -184,14 +189,17 @@ class EngineCore:
     device; the disaggregated workers (``engine/workers.py``) each run only
     their role's pass. ``device`` pins this core's pool (and every pass it
     runs) to one jax device — None keeps the default device, which is also
-    the host-staged fallback for single-device hosts."""
+    the host-staged fallback for single-device hosts.
+    ``keep_logits`` copies the logits behind each request's first and
+    latest token to the host (``EngineRequest.first_logits`` /
+    ``last_logits``; not kept by speculative iterations)."""
 
     def __init__(self, cfg: ModelConfig, params=None, max_batch: int = 4,
                  max_len: int = 512, seed: int = 0, block_tokens: int = 16,
                  num_blocks: Optional[int] = None, preemption: str = "swap",
                  trace_occupancy: bool = False,
                  config: Optional[EngineConfig] = None, draft_params=None,
-                 device=None):
+                 device=None, keep_logits: bool = False):
         assert max_len % block_tokens == 0, \
             "max_len must be a multiple of block_tokens (bit-exact parity " \
             "with the dense engine needs identical logical cache length)"
@@ -219,9 +227,10 @@ class EngineCore:
                            else num_blocks)
         self.preemption = preemption
         self.device = device
+        self.keep_logits = keep_logits
         with self._dev_scope():
             if params is None:
-                params, _ = tf.init_model(cfg, jax.random.PRNGKey(seed))
+                params = tf.init_params(cfg, jax.random.PRNGKey(seed))
             elif device is not None:
                 params = jax.device_put(params, device)
             self.params = params
@@ -282,7 +291,7 @@ class EngineCore:
             dcfg = self.draft_cfg
             with self._dev_scope():
                 if draft_params is None:
-                    draft_params, _ = tf.init_model(
+                    draft_params = tf.init_params(
                         dcfg, jax.random.PRNGKey(self.config.draft_seed))
                 self.draft_params = draft_params
                 # the draft pool is sized so it can NEVER hit pressure:
@@ -486,6 +495,8 @@ class EngineCore:
             self.caches = self._write_prefill(self.caches, dense, ids)
             if r.state == "new":
                 tok = int(jnp.argmax(logits, -1)[0])
+                if self.keep_logits:
+                    r.first_logits = r.last_logits = np.asarray(logits[0])
                 r.first_token_time = time.monotonic()
                 r.tokens.append(tok)
                 r.token_times.append(r.first_token_time)
@@ -643,10 +654,12 @@ class EngineCore:
                 "active": sum(a is not None for a in self.active),
             })
 
-    def _decode_bookkeeping(self, new_tok: np.ndarray):
+    def _decode_bookkeeping(self, new_tok: np.ndarray, logits):
         """Per-row accounting after a decode pass: stream the token, advance
         the store, finish rows that hit a stop condition."""
         now = time.monotonic()
+        if self.keep_logits:
+            logits = np.asarray(logits)
         for s, r in enumerate(self.active):
             if r is None or not self._is_decoding(r):
                 continue
@@ -656,6 +669,8 @@ class EngineCore:
             t = int(new_tok[s])
             r.tokens.append(t)
             r.token_times.append(now)
+            if self.keep_logits:
+                r.last_logits = logits[s]
             done = (len(r.tokens) >= r.max_new_tokens
                     or (r.eos_id is not None and t == r.eos_id)
                     or len(r.prompt) + len(r.tokens) >= self._len_limit - 1)
@@ -681,9 +696,9 @@ class EngineCore:
         for r in dec:
             last[r.slot, 0] = r.tokens[-1]
         self._push_rows(tabs, lens)
-        new_tok, _, self.caches = self._decode(
+        new_tok, logits, self.caches = self._decode(
             self.params, jnp.asarray(last), self.caches)
-        self._decode_bookkeeping(np.asarray(new_tok))
+        self._decode_bookkeeping(np.asarray(new_tok), logits)
 
     # -- chunked prefill pass -------------------------------------------
     def _chunk_budget(self, n_dec: int) -> int:
@@ -735,7 +750,7 @@ class EngineCore:
                 toks[r.slot, :tk] = r.ctx[r.prefilled:r.prefilled + tk]
                 q_valid[r.slot] = tk
             self._push_rows()                  # real tables for every row
-            new_tok, _, self.caches = self._chunk(
+            new_tok, logits, self.caches = self._chunk(
                 self.params, jnp.asarray(toks), jnp.asarray(q_valid),
                 self.caches)
             new_tok = np.asarray(new_tok)
@@ -749,6 +764,9 @@ class EngineCore:
                     # prompt complete: stream the first token (resumes keep
                     # their stream and re-enter decode by feeding tokens[-1])
                     tok = int(new_tok[r.slot])
+                    if self.keep_logits:
+                        r.first_logits = r.last_logits = np.asarray(
+                            logits[r.slot])
                     r.first_token_time = now
                     r.tokens.append(tok)
                     r.token_times.append(now)
@@ -1004,7 +1022,7 @@ def make_engine(cfg: ModelConfig, **kw):
     if paged_supported(cfg):
         return Engine(cfg, **kw)
     for k in ("block_tokens", "num_blocks", "preemption", "trace_occupancy",
-              "config", "draft_params", "device"):
+              "config", "draft_params", "device", "keep_logits"):
         kw.pop(k, None)
     return SlotEngine(cfg, **kw)
 
@@ -1028,7 +1046,7 @@ class SlotEngine:
         self.max_batch = max_batch
         self.max_len = max_len
         if params is None:
-            params, _ = tf.init_model(cfg, jax.random.PRNGKey(seed))
+            params = tf.init_params(cfg, jax.random.PRNGKey(seed))
         self.params = params
         self.caches = tf.init_cache(cfg, max_batch, max_len)
         self.active = [None] * max_batch        # slot -> EngineRequest

@@ -291,6 +291,7 @@ class DisaggEngine:
     * ``prefill_blocks`` / ``decode_blocks`` — per-role pool sizes (None =
       pressure-free default); shrink them to exercise preemption on either
       side of the handoff.
+    * ``keep_logits`` — passed to every worker (``EngineCore``).
     * ``devices`` — optional ``(prefill_devices, decode_devices)`` lists;
       default asks ``launch.mesh.handoff_devices`` (real cross-device
       ``jax.device_put`` when the host has >= 2 devices, host-staged
@@ -305,7 +306,8 @@ class DisaggEngine:
                  decode_blocks: Optional[int] = None,
                  preemption: str = "swap",
                  config: Optional[EngineConfig] = None,
-                 trace_occupancy: bool = False, devices=None):
+                 trace_occupancy: bool = False, devices=None,
+                 keep_logits: bool = False):
         assert mode in ("local", "global")
         assert granularity in ("full", "layerwise")
         assert n_prefill >= 1 and n_decode >= 1
@@ -316,13 +318,14 @@ class DisaggEngine:
         assert config.draft_cfg is None and config.spec_k == 0, \
             "speculative decoding is a single-engine feature for now"
         if params is None:
-            params, _ = tf.init_model(cfg, jax.random.PRNGKey(seed))
+            params = tf.init_params(cfg, jax.random.PRNGKey(seed))
         if devices is None:
             devices = handoff_devices(n_prefill, n_decode)
         pdevs, ddevs = devices
         kw = dict(max_batch=max_batch, max_len=max_len,
                   block_tokens=block_tokens, preemption=preemption,
-                  config=config, trace_occupancy=trace_occupancy)
+                  config=config, trace_occupancy=trace_occupancy,
+                  keep_logits=keep_logits)
         self.prefill = [PrefillWorker(cfg, params, num_blocks=prefill_blocks,
                                       device=pdevs[i], **kw)
                         for i in range(n_prefill)]

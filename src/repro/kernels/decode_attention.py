@@ -27,13 +27,20 @@ Returns ``(b, 1, nh, dv)`` in ``q.dtype``. Scores/softmax accumulate in fp32
 regardless of cache dtype (``preferred_element_type``), matching the jnp
 oracle ``ref.decode_attention`` to fp32 tolerance.
 
-``block_s`` tiles the ``S`` dimension; tiles whose start is past ``lengths``
-skip compute entirely, so the cost of a short request in a long-padded batch
-is proportional to its own length, not to ``S``. The *paged* variant of this
-kernel — same online-softmax structure, but K/V gathered through a
-``(b, max_blocks)`` block table over a pooled ``(num_blocks, block_tokens,
-kvh, d)`` cache — lives in ``kernels/paged_attention.py``; see
-``docs/architecture.md`` for how the two relate to the simulator's allocator.
+``block_s`` tiles the ``S`` dimension (the cache is zero-padded to a whole
+number of tiles when ``S`` is not one); tiles whose start is past
+``lengths`` skip compute entirely, so the cost of a short request in a
+long-padded batch is proportional to its own length, not to ``S``.
+``lengths`` reaches the kernel by scalar prefetch, and the caches are viewed
+as ``(b, S, kvh * d)`` so one head's tile is a ``(1, block_s, d)`` block —
+legal under the TPU's (8, 128) tiling rule for any ``kvh`` when
+``d % 128 == 0``.
+
+The *paged* variant of this kernel — same online-softmax structure, but K/V
+gathered through a ``(b, max_blocks)`` block table over a pooled
+``(num_blocks, block_tokens, kvh, d)`` cache — lives in
+``kernels/paged_attention.py``; see ``docs/architecture.md`` for how the two
+relate to the simulator's allocator.
 """
 from __future__ import annotations
 
@@ -44,14 +51,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer jax renamed it
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    *, scale: float, block_s: int):
+    bi = pl.program_id(0)
     si = pl.program_id(2)
     ns = pl.num_programs(2)
 
@@ -61,31 +66,31 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0]
+    length = len_ref[bi]
     s_start = si * block_s
 
     @pl.when(s_start < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                 # (g, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bs, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)           # (bs, dv)
+        k = k_ref[0].astype(jnp.float32)                    # (bs, d)
+        v = v_ref[0].astype(jnp.float32)                    # (bs, dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         span = s_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(span < length, s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev, l_prev = m_ref[...], l_ref[...]             # (g, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(si == ns - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_s", "interpret"))
@@ -99,28 +104,35 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale: float | None = None
     scale = d ** -0.5 if scale is None else scale
     block_s = min(block_s, S)
     ns = pl.cdiv(S, block_s)
+    pad = [(0, 0), (0, ns * block_s - S), (0, 0)]
+    kf = jnp.pad(k_cache.reshape(b, S, kvh * d), pad)
+    vf = jnp.pad(v_cache.reshape(b, S, kvh * dv), pad)
 
     qr = q.reshape(b, kvh, g, d)
-    grid = (b, kvh, ns)
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, block_s=block_s),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # lengths
+        grid=(b, kvh, ns),
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, hi, si: (bi,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, si: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, d), lambda bi, hi, si: (bi, si, hi, 0)),
-            pl.BlockSpec((1, block_s, 1, dv), lambda bi, hi, si: (bi, si, hi, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda bi, hi, si, lens: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, block_s, d), lambda bi, hi, si, lens: (bi, si, hi)),
+            pl.BlockSpec((1, block_s, dv),
+                         lambda bi, hi, si, lens: (bi, si, hi)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dv), lambda bi, hi, si: (bi, hi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, dv),
+                               lambda bi, hi, si, lens: (bi, hi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, block_s=block_s),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), qr, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), qr, kf, vf)
     return out.reshape(b, 1, nh, dv)

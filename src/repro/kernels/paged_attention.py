@@ -37,7 +37,12 @@ fp32 online-softmax scratch (m, l, acc) carries across a request's pages —
 identical to the dense kernel's structure; the only difference is that the
 K/V BlockSpec index maps read the physical page id from the scalar-prefetched
 block table (``pltpu.PrefetchScalarGridSpec``) instead of slicing a
-contiguous cache. Pages whose first token is past ``lengths`` skip compute
+contiguous cache. The wrapper views each pool as ``(num_blocks,
+block_tokens, kvh * d)`` (a free reshape of the token-major layout), so one
+(page, kv head) tile is the block ``(1, block_tokens, d)``: its last two
+dims equal the page length and a multiple of 128 whenever ``d % 128 == 0``,
+which satisfies the TPU's (8, 128) tiling rule for every ``kvh`` and every
+``block_tokens``. Pages whose first token is past ``lengths`` skip compute
 entirely (``pl.when``); partial tail pages mask per-position. The reference
 oracle (``ref.paged_decode_attention``) gathers the pool into a dense cache
 and reuses the dense oracle, which makes paged-vs-dense parity exact.
@@ -51,10 +56,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer jax renamed it
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
+
+
+def _head_view(k_pool, v_pool):
+    """``(nb, bt, kvh, d)`` pools as ``(nb, bt, kvh * d)``: kv head ``h`` of
+    a page is then the ``(bt, d)`` tile at block index ``(page, 0, h)``."""
+    nb, bt = k_pool.shape[:2]
+    return k_pool.reshape(nb, bt, -1), v_pool.reshape(nb, bt, -1)
 
 
 def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -76,25 +85,25 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(s_start < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                 # (g, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bt, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)           # (bt, dv)
+        k = k_ref[0].astype(jnp.float32)                    # (bt, d)
+        v = v_ref[0].astype(jnp.float32)                    # (bt, dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         span = s_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(span < length, s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev, l_prev = m_ref[...], l_ref[...]             # (g, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(si == ns - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -119,16 +128,16 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
                          lambda bi, hi, si, tab, lens: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bt, 1, d),
-                         lambda bi, hi, si, tab, lens: (tab[bi, si], 0, hi, 0)),
-            pl.BlockSpec((1, bt, 1, dv),
-                         lambda bi, hi, si, tab, lens: (tab[bi, si], 0, hi, 0)),
+            pl.BlockSpec((1, bt, d),
+                         lambda bi, hi, si, tab, lens: (tab[bi, si], 0, hi)),
+            pl.BlockSpec((1, bt, dv),
+                         lambda bi, hi, si, tab, lens: (tab[bi, si], 0, hi)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
                                lambda bi, hi, si, tab, lens: (bi, hi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, dv), jnp.float32),
         ],
     )
@@ -136,12 +145,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         functools.partial(_paged_decode_kernel, scale=scale, block_tokens=bt),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qr, k_pool, v_pool)
+      qr, *_head_view(k_pool, v_pool))
     return out.reshape(b, 1, nh, dv)
 
 
@@ -175,26 +184,26 @@ def _paged_verify_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(s_start < length + s)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                 # (s*g, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bt, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)           # (bt, dv)
+        k = k_ref[0].astype(jnp.float32)                    # (bt, d)
+        v = v_ref[0].astype(jnp.float32)                    # (bt, dv)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         span = s_start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         qpos = length + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0) // g
         sc = jnp.where(span <= qpos, sc, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1))
+        m_prev, l_prev = m_ref[...], l_ref[...]             # (s*g, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new[:, None])
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.exp(sc - m_new)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(si == ns - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -227,16 +236,16 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths, *,
         in_specs=[
             pl.BlockSpec((1, 1, s * g, d),
                          lambda bi, hi, si, tab, lens: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bt, 1, d),
-                         lambda bi, hi, si, tab, lens: (tab[bi, si], 0, hi, 0)),
-            pl.BlockSpec((1, bt, 1, dv),
-                         lambda bi, hi, si, tab, lens: (tab[bi, si], 0, hi, 0)),
+            pl.BlockSpec((1, bt, d),
+                         lambda bi, hi, si, tab, lens: (tab[bi, si], 0, hi)),
+            pl.BlockSpec((1, bt, dv),
+                         lambda bi, hi, si, tab, lens: (tab[bi, si], 0, hi)),
         ],
         out_specs=pl.BlockSpec((1, 1, s * g, dv),
                                lambda bi, hi, si, tab, lens: (bi, hi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((s * g,), jnp.float32),
-            pltpu.VMEM((s * g,), jnp.float32),
+            pltpu.VMEM((s * g, 1), jnp.float32),
+            pltpu.VMEM((s * g, 1), jnp.float32),
             pltpu.VMEM((s * g, dv), jnp.float32),
         ],
     )
@@ -245,11 +254,11 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths, *,
                           s=s, g=g),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, s * g, dv), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qr, k_pool, v_pool)
+      qr, *_head_view(k_pool, v_pool))
     return out.reshape(b, kvh, s, g, dv).transpose(0, 2, 1, 3, 4) \
               .reshape(b, s, nh, dv)

@@ -168,8 +168,7 @@ def _compile_cell(cfg: ModelConfig, shape: ShapeConfig, mesh,
     donate_argnums = (0,) if (donate and shape.kind == "train") else ()
     if donate_cache and shape.kind == "decode":
         donate_argnums = (2,)   # in-place KV-cache update
-    from repro.launch.mesh import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=in_sh,
                          donate_argnums=donate_argnums)
         lowered = jitted.lower(*args)
@@ -183,8 +182,6 @@ def _cost_of(cfg, shape, mesh, fsdp=None, donate_cache=False) -> Dict[str, float
     compiled = _compile_cell(cfg.replace(scan_layers=False), shape, mesh,
                              fsdp=fsdp, donate_cache=donate_cache)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax 0.4.x wraps it in a list
-        ca = ca[0] if ca else {}
     cb = collective_bytes(compiled.as_text())
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -285,8 +282,6 @@ def run_cell(arch: str, shape_name: str, mesh, multi_pod: bool,
                                  donate_cache=donate_cache)
     else:
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # jax 0.4.x wraps it in a list
-            ca = ca[0] if ca else {}
         cost = {"flops": float(ca.get("flops", 0.0)),
                 "bytes": float(ca.get("bytes accessed", 0.0)),
                 "wire": wire_bytes(collective_bytes(compiled.as_text())),

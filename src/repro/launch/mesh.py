@@ -1,68 +1,40 @@
-"""Production mesh factory + jax-version compat shims.
+"""Mesh factories and the disaggregated engine's device assignment.
 
 Factories are FUNCTIONS (not module-level constants) so importing this module
 never touches jax device state. Single pod: (16, 16) = 256 chips
 ("data", "model"). Multi-pod: (2, 16, 16) = 512 chips ("pod", "data",
-"model").
-
-``compat_make_mesh`` / ``mesh_context`` paper over jax API drift:
-* jax >= 0.5 ``jax.make_mesh`` takes ``axis_types``; 0.4.x does not.
-* jax >= 0.5 activates a mesh with ``jax.set_mesh``; on 0.4.x the Mesh
-  object itself is the context manager.
+"model"). Activate a mesh with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
+import math
 from typing import Sequence
 
 import jax
 
 
-def compat_make_mesh(shape: Sequence[int], axes: Sequence[str], *,
-                     shrink: bool = False):
-    """``jax.make_mesh`` across jax versions. With ``shrink=True`` axis
-    sizes are halved (largest-first) until the mesh fits the available
-    device count — so single-host CPU runs still exercise the sharded
-    code paths on a smaller mesh instead of failing the size assertion."""
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              shrink: bool = False):
+    """``jax.make_mesh`` with Auto axes. With ``shrink=True`` axis sizes are
+    halved (largest-first) until the mesh fits the available device count —
+    so single-host CPU runs still exercise the sharded code paths on a
+    smaller mesh instead of failing the size assertion."""
     shape = list(shape)
     if shrink:
         n = jax.device_count()
-        while _prod(shape) > n:
+        while math.prod(shape) > n:
             i = max(range(len(shape)), key=lambda j: shape[j])
             if shape[i] == 1:
                 break
             shape[i] = max(1, shape[i] // 2)
-    try:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    except (TypeError, AttributeError):   # jax 0.4.x: no axis_types kwarg
-        return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def mesh_context(mesh):
-    """Context manager that makes ``mesh`` the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh   # 0.4.x: Mesh is itself a context manager
-
-
-def _prod(xs) -> int:
-    out = 1
-    for x in xs:
-        out *= int(x)
-    return out
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
-
-
-def make_local_mesh():
-    """Whatever devices exist locally, as a 1-D 'data' mesh (smoke tests)."""
-    n = len(jax.devices())
-    return compat_make_mesh((n,), ("data",))
+    return make_mesh(shape, axes)
 
 
 def handoff_devices(n_prefill: int, n_decode: int):

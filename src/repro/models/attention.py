@@ -46,8 +46,9 @@ def init_attention(init: Initializer, path: str, cfg: ModelConfig) -> Dict:
                           ("kv_lora", "heads", "head_dim"))
         p["wuv"] = init.w(f"{path}.wuv", (m.kv_lora_rank, cfg.num_heads, m.v_head_dim),
                           ("kv_lora", "heads", "head_dim"))
-        p["wo"] = init.z(f"{path}.wo", (cfg.num_heads, m.v_head_dim, d),
-                         ("heads", "head_dim", "w_embed"))
+        p["wo"] = init.out(f"{path}.wo", (cfg.num_heads, m.v_head_dim, d),
+                           ("heads", "head_dim", "w_embed"),
+                           cfg.num_heads * m.v_head_dim)
         return p
     # GQA / MQA / MHA. Baseline tags head_dim with the "head_dim_shard"
     # fallback (takes "model" only when heads couldn't). v2 drops it: rope's
@@ -62,8 +63,8 @@ def init_attention(init: Initializer, path: str, cfg: ModelConfig) -> Dict:
                      ("w_embed", "kv_heads", hd_ax)),
         "wv": init.w(f"{path}.wv", (d, cfg.num_kv_heads, hd),
                      ("w_embed", "kv_heads", hd_ax)),
-        "wo": init.z(f"{path}.wo", (cfg.num_heads, hd, d),
-                     ("heads", hd_ax, "w_embed")),
+        "wo": init.out(f"{path}.wo", (cfg.num_heads, hd, d),
+                       ("heads", hd_ax, "w_embed"), cfg.num_heads * hd),
     }
 
 

@@ -24,9 +24,10 @@ class Initializer:
     effect so it is populated either way.
     """
 
-    def __init__(self, cfg: ModelConfig, key):
+    def __init__(self, cfg: ModelConfig, key, zero_out: bool = True):
         self.cfg = cfg
         self.key = key
+        self.zero_out = zero_out
         self.dtype = jnp.dtype(cfg.param_dtype)
         self.axes: Dict[str, Tuple] = {}
 
@@ -41,8 +42,16 @@ class Initializer:
         return (jax.random.truncated_normal(k, -2.0, 2.0, shape, jnp.float32)
                 * scale).astype(self.dtype)
 
+    def out(self, path: str, shape, axes, fan_in: int):
+        """Output projection (attention, MLP, expert ``wo``): zero under the
+        training init (``zero_out``), so every block starts as the identity;
+        fan-in normal otherwise, so random weights exercise every block."""
+        if self.zero_out:
+            return self.z(path, shape, axes)
+        return self.w(path, shape, axes, scale=fan_in ** -0.5)
+
     def z(self, path: str, shape, axes):
-        """Zero-init weight (output projections, biases)."""
+        """Zero-init weight (biases, norm offsets, training-init ``out``)."""
         self.axes[path] = tuple(axes)
         return jnp.zeros(shape, self.dtype)
 
@@ -130,10 +139,9 @@ def init_mlp(init: Initializer, path: str, cfg: ModelConfig, d_ff: Optional[int]
     p = {}
     if cfg.mlp_type in ("swiglu", "geglu"):
         p["wi"] = init.w(f"{path}.wi", (d, 2, f), ("w_embed", None, "ff"))
-        p["wo"] = init.z(f"{path}.wo", (f, d), ("ff", "w_embed"))
     else:  # relu2 | gelu
         p["wi"] = init.w(f"{path}.wi", (d, f), ("w_embed", "ff"))
-        p["wo"] = init.z(f"{path}.wo", (f, d), ("ff", "w_embed"))
+    p["wo"] = init.out(f"{path}.wo", (f, d), ("ff", "w_embed"), f)
     return p
 
 

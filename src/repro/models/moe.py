@@ -20,21 +20,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:                                  # jax >= 0.6 top-level export
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_REP_KW = "check_vma"
-except ImportError:                   # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_REP_KW = "check_rep"
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=False):
-    """Version-portable shard_map: newer jax calls the replication-check
-    knob ``check_vma``, 0.4.x calls it ``check_rep``."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_SHARD_MAP_REP_KW: check_vma})
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import Initializer, init_mlp, apply_mlp
@@ -50,7 +37,8 @@ def init_moe(init: Initializer, path: str, cfg: ModelConfig) -> Dict:
                          scale=d ** -0.5),
         "wi": init.w(f"{path}.wi", (m.num_experts, d, (2 * f if glu else f)),
                      ("experts", "w_embed", "ff")),
-        "wo": init.z(f"{path}.wo", (m.num_experts, f, d), ("experts", "ff", "w_embed")),
+        "wo": init.out(f"{path}.wo", (m.num_experts, f, d),
+                       ("experts", "ff", "w_embed"), f),
     }
     if m.num_shared_experts:
         p["shared"] = init_mlp(init, f"{path}.shared", cfg, d_ff=m.shared_d_ff)

@@ -53,13 +53,13 @@ def _init_block(init: Initializer, prefix: str, cfg: ModelConfig, moe_layer: boo
     return p
 
 
-def _stacked(cfg: ModelConfig, key, build, n: int):
+def _stacked(cfg: ModelConfig, key, build, n: int, zero_out: bool = True):
     """Stack ``n`` copies of ``build(init)`` on a leading scan axis; returns
     (params, flat-axes-with-scan-prefix)."""
     axes = {}
     trees = []
     for i in range(n):
-        ini = Initializer(cfg, jax.random.fold_in(key, i))
+        ini = Initializer(cfg, jax.random.fold_in(key, i), zero_out)
         trees.append(build(ini))
         axes = ini.axes
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs, 0), *trees)
@@ -67,9 +67,15 @@ def _stacked(cfg: ModelConfig, key, build, n: int):
     return stacked, axes
 
 
-def init_model(cfg: ModelConfig, key) -> Tuple[Dict, Dict[str, tuple]]:
-    """Returns (params, flat axes dict path->logical axes)."""
-    init = Initializer(cfg, jax.random.fold_in(key, 0xE0))
+def init_model(cfg: ModelConfig, key,
+               zero_out: bool = True) -> Tuple[Dict, Dict[str, tuple]]:
+    """Returns (params, flat axes dict path->logical axes).
+
+    ``zero_out`` is the training init: attention, MLP and expert output
+    projections start at zero, so every block is the identity and the
+    logits depend on the last token only. Serving random weights passes
+    ``zero_out=False`` so attention and the KV cache reach the logits."""
+    init = Initializer(cfg, jax.random.fold_in(key, 0xE0), zero_out)
     flat_axes: Dict[str, tuple] = {}
     params: Dict = {}
 
@@ -90,24 +96,27 @@ def init_model(cfg: ModelConfig, key) -> Tuple[Dict, Dict[str, tuple]]:
     if cfg.family in ("dense", "vlm", "audio"):
         params["layers"], ax = _stacked(
             cfg, jax.random.fold_in(key, 1),
-            lambda ini: _init_block(ini, "layers", cfg, False), cfg.num_layers)
+            lambda ini: _init_block(ini, "layers", cfg, False), cfg.num_layers,
+            zero_out)
         flat_axes.update(ax)
     elif cfg.family == "moe":
         kd = cfg.moe.first_k_dense
         params["dense_layers"], ax = _stacked(
             cfg, jax.random.fold_in(key, 1),
-            lambda ini: _init_block(ini, "dense_layers", cfg, False), kd)
+            lambda ini: _init_block(ini, "dense_layers", cfg, False), kd,
+            zero_out)
         flat_axes.update(ax)
         params["layers"], ax = _stacked(
             cfg, jax.random.fold_in(key, 2),
-            lambda ini: _init_block(ini, "layers", cfg, True), cfg.num_layers - kd)
+            lambda ini: _init_block(ini, "layers", cfg, True),
+            cfg.num_layers - kd, zero_out)
         flat_axes.update(ax)
     elif cfg.family == "hybrid":
         params["mamba"], ax = _stacked(
             cfg, jax.random.fold_in(key, 1),
             lambda ini: m2.init_mamba2(ini, "mamba", cfg), cfg.num_layers)
         flat_axes.update(ax)
-        ini = Initializer(cfg, jax.random.fold_in(key, 2))
+        ini = Initializer(cfg, jax.random.fold_in(key, 2), zero_out)
         params["shared"] = _init_block(ini, "shared", cfg, False)
         flat_axes.update(ini.axes)
     elif cfg.family == "ssm":
@@ -124,6 +133,15 @@ def init_model(cfg: ModelConfig, key) -> Tuple[Dict, Dict[str, tuple]]:
     else:
         raise ValueError(cfg.family)
     return params, flat_axes
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def init_params(cfg: ModelConfig, key, zero_out: bool = True):
+    """``init_model``'s params built by one compiled program (bitwise equal
+    to the eager build). At published width the eager build holds every
+    per-layer tree, its f32 temporaries and the stacked copy in device
+    memory at once; one program lets XLA write the stacked arrays directly."""
+    return init_model(cfg, key, zero_out)[0]
 
 
 def axes_tree(params, flat_axes):

@@ -1,23 +1,7 @@
-import importlib.util
 import os
-import sys
 
 # keep smoke tests on 1 device; the dry-run (and ONLY the dry-run) forces 512
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# Property tests want hypothesis; fall back to the bundled miniature shim
-# (seeded random sweeps, same decorator surface) when it isn't installed.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _spec = importlib.util.spec_from_file_location(
-        "_hypothesis_fallback",
-        os.path.join(os.path.dirname(__file__), "_hypothesis_fallback.py"))
-    _fb = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_fb)
-    _mod = _fb.build_module()
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies
 
 import jax  # noqa: E402
 

@@ -40,7 +40,9 @@ def cfg():
 
 @pytest.fixture(scope="module")
 def params(cfg):
-    p, _ = tf.init_model(cfg, jax.random.PRNGKey(0))
+    # live output projections: under the training init every block is the
+    # identity, so streams would not depend on the KV cache at all
+    p, _ = tf.init_model(cfg, jax.random.PRNGKey(0), zero_out=False)
     return p
 
 

@@ -35,7 +35,9 @@ def cfg():
 
 @pytest.fixture(scope="module")
 def params(cfg):
-    return tf.init_model(cfg, jax.random.PRNGKey(3))[0]
+    # live output projections: under the training init every block is the
+    # identity, so streams would not depend on the KV cache at all
+    return tf.init_model(cfg, jax.random.PRNGKey(3), zero_out=False)[0]
 
 
 @pytest.fixture(scope="module")

@@ -9,13 +9,12 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Every snippet builds its mesh through compat_make_mesh(..., shrink=True):
-# works across jax versions (no axis_types on 0.4.x) and shrinks the mesh
-# instead of tripping the "mesh requires N devices" assertion when the
-# subprocess ends up with fewer devices than requested (single-host CPU).
+# Every snippet builds its mesh through make_mesh(..., shrink=True), which
+# shrinks the mesh instead of tripping the "mesh requires N devices"
+# assertion when the subprocess ends up with fewer devices than requested.
 _PRELUDE = """
     import jax
-    from repro.launch.mesh import compat_make_mesh, mesh_context
+    from repro.launch.mesh import make_mesh
 """
 
 
@@ -39,7 +38,7 @@ def test_moe_ep_matches_single_device():
         from repro.configs import get_reduced_config
         from repro.models import moe as moe_mod
         from repro.models.layers import Initializer
-        mesh = compat_make_mesh((2, 4), ("data", "model"), shrink=True)
+        mesh = make_mesh((2, 4), ("data", "model"), shrink=True)
         key = jax.random.PRNGKey(0)
         cfg = get_reduced_config("deepseek_v2_lite_16b").replace(
             param_dtype="float32", compute_dtype="float32")
@@ -69,7 +68,7 @@ def test_sharded_train_step_runs_and_matches():
         from repro.models.sharding import ShardingRules, tree_specs
         cfg = get_reduced_config("internlm2_20b").replace(
             param_dtype="float32", compute_dtype="float32", remat="none")
-        mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"),
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),
                                 shrink=True)
         rules = ShardingRules(mesh)
         key = jax.random.PRNGKey(0)
@@ -78,7 +77,7 @@ def test_sharded_train_step_runs_and_matches():
                  "labels": jax.random.randint(jax.random.fold_in(key, 1),
                                               (8, 32), 0, cfg.vocab_size)}
         _, m1 = steps.train_step(state, batch, cfg)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             fn = jax.jit(lambda s, b: steps.train_step(s, b, cfg, rules=rules,
                                                        mesh=mesh))
             _, m2 = fn(state, batch)
@@ -93,7 +92,7 @@ def test_dryrun_single_cell_on_small_mesh():
     """The dry-run machinery end-to-end on an 8-device (2,2,2) mesh."""
     out = _run("""
         from repro.launch import dryrun
-        mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"),
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),
                                 shrink=True)
         from repro.configs import get_reduced_config
         cfg = get_reduced_config("internlm2_20b")
@@ -110,7 +109,7 @@ def test_dryrun_single_cell_on_small_mesh():
 def test_mesh_shrinks_to_fit_device_count():
     """shrink=True never requests more devices than exist (1-device run)."""
     out = _run("""
-        mesh = compat_make_mesh((2, 4), ("data", "model"), shrink=True)
+        mesh = make_mesh((2, 4), ("data", "model"), shrink=True)
         assert mesh.devices.size <= jax.device_count(), mesh.shape
         print("SHRINK_OK", dict(mesh.shape))
     """, devices=1)

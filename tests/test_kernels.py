@@ -1,11 +1,11 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracle across
-shape/dtype sweeps."""
+shape/dtype sweeps, and the ``ops`` dispatch rules and record."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.pq_scan import pq_scan
@@ -92,3 +92,48 @@ def test_chunked_flash_matches_ref(bq, bk):
                                          block_q=bq, block_k=bk)
         o2 = ref.flash_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(o1, o2, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kvh", [1, 8])
+def test_kernels_lane_width_heads(kvh):
+    """128-lane head dims, as the chip runs them, for MQA and GQA: flash
+    (with a ragged prompt that pads to whole tiles) and dense decode."""
+    q, k, v = _qkv(2, 200, 8, kvh, 128, dtype=jnp.bfloat16)
+    out = flash_attention(q, k, v, causal=True, interpret=True)
+    want = ref.flash_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(out.astype(np.float32),
+                               want.astype(np.float32), atol=3e-2, rtol=3e-2)
+    lengths = jnp.array([1, 200], jnp.int32)
+    out = decode_attention(q[:, :1], k, v, lengths, interpret=True,
+                           block_s=64)
+    want = ref.decode_attention(q[:, :1], k, v, lengths)
+    np.testing.assert_allclose(out.astype(np.float32),
+                               want.astype(np.float32), atol=3e-2, rtol=3e-2)
+
+
+def test_ops_dispatch_rules_and_record(monkeypatch):
+    """Off the TPU every op takes the reference; on it (steered here) the
+    kernel runs exactly when the head dims are whole 128-lane tiles, and
+    ``paged_chunk_attention`` always records the reference. Choices are
+    counted per trace (fresh lambdas, so no trace is served from a cache)."""
+    def trace_all():
+        ops.DISPATCH.clear()
+        for d in (64, 128):
+            q, k, v = _qkv(1, 16, 4, 1, d)
+            jax.eval_shape(lambda *a: ops.flash_attention(*a), q, k, v)
+            pool = jnp.zeros((3, 8, 1, d))
+            tab = jnp.zeros((1, 2), jnp.int32)
+            lens = jnp.ones((1,), jnp.int32)
+            for fn in (ops.paged_decode_attention, ops.paged_chunk_attention):
+                jax.eval_shape(lambda *a, fn=fn: fn(*a), q[:, :1], pool,
+                               pool, tab, lens)
+        return ops.dispatch_record()
+
+    assert trace_all() == {"flash_attention": {"ref": 2},
+                           "paged_chunk_attention": {"ref": 2},
+                           "paged_decode_attention": {"ref": 2}}
+    monkeypatch.setattr(ops, "_platform", lambda: "tpu")
+    assert trace_all() == {
+        "flash_attention": {"pallas": 1, "ref": 1},
+        "paged_chunk_attention": {"ref": 2},
+        "paged_decode_attention": {"pallas": 1, "ref": 1}}

@@ -15,6 +15,7 @@ from repro.engine.paged_kv import PagedKVStore, prefix_chain
 from repro.engine.runner import Engine, SlotEngine, make_engine
 from repro.kernels import ref
 from repro.kernels.paged_attention import paged_decode_attention
+from repro.models import transformer as tf
 
 KEY = jax.random.PRNGKey(7)
 
@@ -52,6 +53,26 @@ def test_paged_kernel_matches_dense_ref(b, kvh, g, d, bt, mb, seed):
     dense_k = ref.gather_paged_kv(kp, tab)
     dense_v = ref.gather_paged_kv(vp, tab)
     want = ref.decode_attention(q, dense_k, dense_v, lens)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kvh", [1, 4])
+@pytest.mark.parametrize("verify", [False, True])
+def test_paged_kernels_lane_width_heads(kvh, verify):
+    """Head dims of 128 lanes, as the chip runs them, over one kv head (MQA)
+    and several (GQA): each (page, kv head) tile of the head-sliced pool
+    view must reach its own query group."""
+    from repro.kernels.paged_attention import paged_verify_attention
+    q, kp, vp, tab, lens = _pool_case(jax.random.fold_in(KEY, 40 + kvh),
+                                      3, kvh, 2, 128, 128, 16, 4)
+    if verify:
+        q = jnp.concatenate([q, 0.5 * q, -q], axis=1)    # s = 3 positions
+        lens = jnp.minimum(lens, 4 * 16 - 3)
+        out = paged_verify_attention(q, kp, vp, tab, lens, interpret=True)
+        want = ref.paged_verify_attention(q, kp, vp, tab, lens)
+    else:
+        out = paged_decode_attention(q, kp, vp, tab, lens, interpret=True)
+        want = ref.paged_decode_attention(q, kp, vp, tab, lens)
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
@@ -186,9 +207,16 @@ def prompts(cfg):
     return [rng.integers(0, cfg.vocab_size, n) for n in (12, 17, 12, 17, 12)]
 
 
-def test_paged_engine_matches_slot_engine(cfg, prompts):
-    slot = SlotEngine(cfg, max_batch=2, max_len=64, seed=3)
-    paged = Engine(cfg, max_batch=2, max_len=64, seed=3, block_tokens=16)
+@pytest.fixture(scope="module")
+def params(cfg):
+    # live output projections: under the training init every block is the
+    # identity, so streams would not depend on the KV cache at all
+    return tf.init_params(cfg, jax.random.PRNGKey(3), False)
+
+
+def test_paged_engine_matches_slot_engine(cfg, params, prompts):
+    slot = SlotEngine(cfg, params, max_batch=2, max_len=64)
+    paged = Engine(cfg, params, max_batch=2, max_len=64, block_tokens=16)
     for p in prompts:
         slot.submit(p, max_new_tokens=5)
         paged.submit(p, max_new_tokens=5)
@@ -200,12 +228,12 @@ def test_paged_engine_matches_slot_engine(cfg, prompts):
 
 
 @pytest.mark.parametrize("policy", ["swap", "recompute"])
-def test_pressured_engine_stream_parity(cfg, prompts, policy):
+def test_pressured_engine_stream_parity(cfg, params, prompts, policy):
     """A pool too small for both requests forces real mid-stream preemption
     (device->host page movement for swap; drop + re-prefill for recompute);
     the token streams must still equal the unpressured engine's."""
-    ample = Engine(cfg, max_batch=2, max_len=64, seed=5, block_tokens=8)
-    tight = Engine(cfg, max_batch=2, max_len=64, seed=5, block_tokens=8,
+    ample = Engine(cfg, params, max_batch=2, max_len=64, block_tokens=8)
+    tight = Engine(cfg, params, max_batch=2, max_len=64, block_tokens=8,
                    num_blocks=5, preemption=policy)
     for p in prompts[:2]:
         ample.submit(p, max_new_tokens=12)
