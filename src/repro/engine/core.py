@@ -77,8 +77,10 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
+from repro.engine import spans
 from repro.engine.paged_kv import PagedKVStore, prefix_chain
 from repro.models import steps
 from repro.models import transformer as tf
@@ -528,12 +530,13 @@ class EngineCore:
         self._draft_valid[r.rid] = len(ctx)
 
     def _admit(self):
-        for slot in range(self.max_batch):
-            if self.active[slot] is not None or not self.waiting:
-                continue
-            if not self._admit_one(slot, self.waiting[0]):
-                break
-            self.waiting.pop(0)
+        with TraceAnnotation(spans.ADMIT):
+            for slot in range(self.max_batch):
+                if self.active[slot] is not None or not self.waiting:
+                    continue
+                if not self._admit_one(slot, self.waiting[0]):
+                    break
+                self.waiting.pop(0)
 
     # -- preemption -----------------------------------------------------
     def preempt_slot(self, slot: int, policy: Optional[str] = None):
@@ -545,38 +548,40 @@ class EngineCore:
         if r is None:
             return
         policy = policy or self.preemption
-        rid = r.rid
-        if self.spec:
-            # a mid-step victim may hold a speculative fork: roll the target
-            # table back to its committed base before swap/drop, and drop the
-            # draft KV outright (rebuilt by _admit_draft on resume)
-            if rid in self.store.forks:
-                self.store.abort_fork(rid)
-            if rid in self.draft_store.tables:
-                self.draft_store.free(rid)
-            self._draft_valid.pop(rid, None)
-            self._draft_tables_np[slot] = self.draft_store.trash_block
-            self._draft_lengths_np[slot] = 0
-        if policy == "swap":
-            blocks = self.store.swap_out(rid)
-            if blocks is None:                 # shared pages: degrade
-                policy = "recompute"
-            else:
-                # gather exactly the victim's pages (not the trash-padded
-                # table): host memory and the device->host transfer scale
-                # with the request, not with max_blocks
-                ids = jnp.asarray(np.asarray(blocks, np.int32))
-                pages = self._gather_pages(self.caches, ids)
-                self.store.tables[rid].host_pages = jax.device_get(pages)
-                r.state = "swapped"
-        if policy == "recompute":
-            self.store.drop(rid)
-            r.state = "preempted"
-        r.preemptions += 1
-        self.active[slot] = None
-        r.slot = None
-        self._clear_row(slot)
-        self.enqueue(r)
+        with TraceAnnotation(spans.PREEMPT):
+            rid = r.rid
+            if self.spec:
+                # a mid-step victim may hold a speculative fork: roll the
+                # target table back to its committed base before swap/drop,
+                # and drop the draft KV outright (rebuilt by _admit_draft on
+                # resume)
+                if rid in self.store.forks:
+                    self.store.abort_fork(rid)
+                if rid in self.draft_store.tables:
+                    self.draft_store.free(rid)
+                self._draft_valid.pop(rid, None)
+                self._draft_tables_np[slot] = self.draft_store.trash_block
+                self._draft_lengths_np[slot] = 0
+            if policy == "swap":
+                blocks = self.store.swap_out(rid)
+                if blocks is None:                 # shared pages: degrade
+                    policy = "recompute"
+                else:
+                    # gather exactly the victim's pages (not the trash-padded
+                    # table): host memory and the device->host transfer scale
+                    # with the request, not with max_blocks
+                    ids = jnp.asarray(np.asarray(blocks, np.int32))
+                    pages = self._gather_pages(self.caches, ids)
+                    self.store.tables[rid].host_pages = jax.device_get(pages)
+                    r.state = "swapped"
+            if policy == "recompute":
+                self.store.drop(rid)
+                r.state = "preempted"
+            r.preemptions += 1
+            self.active[slot] = None
+            r.slot = None
+            self._clear_row(slot)
+            self.enqueue(r)
 
     def _make_room(self, for_rid: int) -> bool:
         """Free blocks by preempting the most-recently-admitted other active
@@ -598,34 +603,36 @@ class EngineCore:
     def _grow_active(self):
         """Fault in pages so every active DECODE row's table covers the KV
         slot its next decode write lands in; exhaustion preempts victims."""
-        for slot in range(self.max_batch):
-            r = self.active[slot]      # re-read: _make_room may evict slots
-            if r is None or not self._is_decoding(r) \
-                    or not self.store.needs_block(r.rid):
-                continue
-            while True:
-                b = self.store.grow(r.rid)
-                if b is not None:
-                    self._tables_np[r.slot,
-                                    len(self.store.tables[r.rid].blocks) - 1] = b
-                    break
-                if not self._make_room(r.rid):
-                    raise RuntimeError(
-                        "KV pool exhausted with no preemptable victim")
+        with TraceAnnotation(spans.GROW):
+            for slot in range(self.max_batch):
+                r = self.active[slot]  # re-read: _make_room may evict slots
+                if r is None or not self._is_decoding(r) \
+                        or not self.store.needs_block(r.rid):
+                    continue
+                while True:
+                    b = self.store.grow(r.rid)
+                    if b is not None:
+                        n = len(self.store.tables[r.rid].blocks)
+                        self._tables_np[r.slot, n - 1] = b
+                        break
+                    if not self._make_room(r.rid):
+                        raise RuntimeError(
+                            "KV pool exhausted with no preemptable victim")
 
     def _grow_to(self, r: EngineRequest, target_tokens: int):
         """Fault pages until ``r``'s table covers ``target_tokens`` KV slots
         (chunk-phase growth at the fill front); exhaustion preempts victims
         — never ``r`` itself."""
         t = self.store.tables[r.rid]
-        while len(t.blocks) * self.block_tokens < target_tokens:
-            b = self.store.grow(r.rid)
-            if b is not None:
-                self._tables_np[r.slot, len(t.blocks) - 1] = b
-                continue
-            if not self._make_room(r.rid):
-                raise RuntimeError(
-                    "KV pool exhausted with no preemptable victim")
+        with TraceAnnotation(spans.GROW):
+            while len(t.blocks) * self.block_tokens < target_tokens:
+                b = self.store.grow(r.rid)
+                if b is not None:
+                    self._tables_np[r.slot, len(t.blocks) - 1] = b
+                    continue
+                if not self._make_room(r.rid):
+                    raise RuntimeError(
+                        "KV pool exhausted with no preemptable victim")
 
     def _finish(self, r: EngineRequest, now: float):
         r.finish_time = now
@@ -643,7 +650,9 @@ class EngineCore:
         self._clear_row(r.slot)
         r.slot = None
 
-    def _trace_step(self):
+    def _count_step(self):
+        """Count one iteration and, with ``trace_occupancy``, record the
+        pool's occupancy after it."""
         self.steps += 1
         if self.trace_occupancy:
             st = self.store
@@ -686,19 +695,26 @@ class EngineCore:
                if r is not None and self._is_decoding(r)]
         if not dec:
             return
-        tabs = self._tables_np.copy()
-        lens = self._lengths_np.copy()
-        for r in self.active:
-            if r is not None and not self._is_decoding(r):
-                tabs[r.slot] = self.store.trash_block
-                lens[r.slot] = 0
-        last = np.zeros((self.max_batch, 1), np.int32)
-        for r in dec:
-            last[r.slot, 0] = r.tokens[-1]
-        self._push_rows(tabs, lens)
-        new_tok, logits, self.caches = self._decode(
-            self.params, jnp.asarray(last), self.caches)
-        self._decode_bookkeeping(np.asarray(new_tok), logits)
+        with TraceAnnotation(spans.DECODE):
+            tabs = self._tables_np.copy()
+            lens = self._lengths_np.copy()
+            for r in self.active:
+                if r is not None and not self._is_decoding(r):
+                    tabs[r.slot] = self.store.trash_block
+                    lens[r.slot] = 0
+            last = np.zeros((self.max_batch, 1), np.int32)
+            for r in dec:
+                last[r.slot, 0] = r.tokens[-1]
+            with TraceAnnotation(spans.DECODE_PUSH):
+                self._push_rows(tabs, lens)
+                last = jnp.asarray(last)
+            new_tok, logits, self.caches = spans.dispatch(
+                spans.DECODE_DISPATCH, self._decode, self.params, last,
+                self.caches)
+            with TraceAnnotation(spans.DECODE_SYNC):
+                new_tok = np.asarray(new_tok)
+            with TraceAnnotation(spans.DECODE_EMIT):
+                self._decode_bookkeeping(new_tok, logits)
 
     # -- chunked prefill pass -------------------------------------------
     def _chunk_budget(self, n_dec: int) -> int:
@@ -721,6 +737,17 @@ class EngineCore:
         Chunk scheduling: admit-order fairness, shared token budget.
         ``_grow_to`` may preempt victims (most-recently-admitted), including
         rows already scheduled this pass — takes are re-validated after."""
+        with TraceAnnotation(spans.CHUNK) as s:
+            with TraceAnnotation(spans.CHUNK_SCHEDULE):
+                takes = self._schedule_chunks()
+            if takes:
+                self._run_chunks(takes)
+            s.set_metadata(tokens=sum(takes.values()),
+                           positions=(self.max_batch * self.chunk_size
+                                      if takes else 0))
+
+    def _schedule_chunks(self) -> Dict[int, int]:
+        """rid -> prompt tokens each chunk-phase row writes this pass."""
         chunkers = sorted(
             (r for r in self.active
              if r is not None and not self._is_decoding(r)),
@@ -739,21 +766,27 @@ class EngineCore:
             takes[r.rid] = take
             budget -= take
         alive = {r.rid for r in self.active if r is not None}
-        takes = {rid: tk for rid, tk in takes.items() if rid in alive}
-        if takes:
-            toks = np.zeros((self.max_batch, self.chunk_size), np.int32)
-            q_valid = np.zeros((self.max_batch,), np.int32)
-            rows = [r for r in self.active
-                    if r is not None and r.rid in takes]
-            for r in rows:
-                tk = takes[r.rid]
-                toks[r.slot, :tk] = r.ctx[r.prefilled:r.prefilled + tk]
-                q_valid[r.slot] = tk
+        return {rid: tk for rid, tk in takes.items() if rid in alive}
+
+    def _run_chunks(self, takes: Dict[int, int]):
+        """Dispatch the chunk program over ``takes`` and stream the first
+        token of every prompt it completes."""
+        toks = np.zeros((self.max_batch, self.chunk_size), np.int32)
+        q_valid = np.zeros((self.max_batch,), np.int32)
+        rows = [r for r in self.active if r is not None and r.rid in takes]
+        for r in rows:
+            tk = takes[r.rid]
+            toks[r.slot, :tk] = r.ctx[r.prefilled:r.prefilled + tk]
+            q_valid[r.slot] = tk
+        with TraceAnnotation(spans.CHUNK_PUSH):
             self._push_rows()                  # real tables for every row
-            new_tok, logits, self.caches = self._chunk(
-                self.params, jnp.asarray(toks), jnp.asarray(q_valid),
-                self.caches)
+            toks, q_valid = jnp.asarray(toks), jnp.asarray(q_valid)
+        new_tok, logits, self.caches = spans.dispatch(
+            spans.CHUNK_DISPATCH, self._chunk, self.params, toks, q_valid,
+            self.caches)
+        with TraceAnnotation(spans.CHUNK_SYNC):
             new_tok = np.asarray(new_tok)
+        with TraceAnnotation(spans.CHUNK_EMIT):
             now = time.monotonic()
             for r in rows:
                 tk = takes[r.rid]
@@ -770,7 +803,6 @@ class EngineCore:
                     r.first_token_time = now
                     r.tokens.append(tok)
                     r.token_times.append(now)
-        self._trace_step()
 
     def kv_stats(self) -> Dict[str, float]:
         return self.store.stats()
@@ -785,7 +817,6 @@ class Engine(EngineCore):
         """Legacy whole-prefill iteration: one (b, 1) decode pass."""
         self._grow_active()
         self._decode_pass()
-        self._trace_step()
 
     def _step_mixed(self):
         """One mixed iteration: (a) the decode pass for decode-phase rows —
@@ -904,7 +935,6 @@ class Engine(EngineCore):
         live = [r for r in live
                 if r.slot is not None and self.active[r.slot] is r]
         if not live:
-            self._trace_step()
             return
         toks = np.zeros((self.max_batch, self.spec_k + 1), np.int32)
         q_valid = np.zeros((self.max_batch,), np.int32)
@@ -956,7 +986,6 @@ class Engine(EngineCore):
                 self._draft_valid[r.rid] = min(L + m,
                                                L + 1 + min(k - 1, a, m))
         self.spec_iters += 1
-        self._trace_step()
 
     def spec_stats(self) -> Dict[str, object]:
         """Acceptance telemetry for calibration: the measured per-position
@@ -998,11 +1027,14 @@ class Engine(EngineCore):
             step = self._step_spec
         else:
             step = self._step_mixed if self.chunk_size else self._step_decode
-        while (self.waiting or any(a is not None for a in self.active)) \
-                and self.steps < max_steps:
-            self._admit()
-            if any(a is not None for a in self.active):
-                step()
+        with TraceAnnotation(spans.RUN):
+            while (self.waiting or any(a is not None for a in self.active)) \
+                    and self.steps < max_steps:
+                with TraceAnnotation(spans.STEP):
+                    self._admit()
+                    if any(a is not None for a in self.active):
+                        step()
+                        self._count_step()
         return self.finished
 
 

@@ -187,6 +187,7 @@ class PrefillWorker(EngineCore):
                     r is not None and not self._is_decoding(r)
                     for r in self.active):
                 self._chunk_pass()
+                self._count_step()
                 worked = True
             return bool(self._export_ready()) or worked
 
@@ -267,7 +268,7 @@ class DecodeWorker(EngineCore):
             if any(a is not None for a in self.active):
                 self._grow_active()
                 self._decode_pass()
-                self._trace_step()
+                self._count_step()
                 worked = True
             # recompute victims need a prefill worker to rebuild their KV
             out = [r for r in self.waiting if r.state == "preempted"]

@@ -10,10 +10,15 @@ it.
 Every choice is counted in ``DISPATCH`` as ``(op, "pallas" | "ref")`` when
 the op is traced (once per compiled program, not per call), so a run can show
 which implementation each op of its path took (``dispatch_record``).
+
+Each op runs under ``jax.named_scope(<op name>)``, kernel and reference
+alike, so the compiled program's ``op_name`` metadata names the op every
+instruction belongs to (``bench/enginetrace.py`` reads it to name device ops).
 """
 from __future__ import annotations
 
 import collections
+import functools
 from typing import Dict
 
 import jax
@@ -45,11 +50,21 @@ def _use_kernel(op: str, fits: bool) -> bool:
     return use
 
 
+def _scoped(op):
+    """Run ``op`` under a named scope of its own name."""
+    @functools.wraps(op)
+    def run(*args, **kw):
+        with jax.named_scope(op.__name__):
+            return op(*args, **kw)
+    return run
+
+
 def _lane_dims(*dims: int) -> bool:
     """Head dims the kernels tile as whole 128-lane blocks."""
     return all(d % 128 == 0 for d in dims)
 
 
+@_scoped
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None):
     if _use_kernel("flash_attention", _lane_dims(q.shape[-1], v.shape[-1])):
         return _fa.flash_attention(q, k, v, causal=causal, scale=scale)
@@ -61,6 +76,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None)
     return _ref.flash_attention(q, k, v, causal=causal, scale=scale)
 
 
+@_scoped
 def decode_attention(q, k_cache, v_cache, lengths, *, scale: float | None = None):
     if _use_kernel("decode_attention",
                    _lane_dims(q.shape[-1], v_cache.shape[-1])):
@@ -68,6 +84,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale: float | None = None
     return _ref.decode_attention(q, k_cache, v_cache, lengths, scale=scale)
 
 
+@_scoped
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
                            scale: float | None = None):
     """Block-table-indexed decode attention over pooled KV pages (see
@@ -80,6 +97,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
                                        lengths, scale=scale)
 
 
+@_scoped
 def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths, *,
                            scale: float | None = None):
     """Speculative-verify attention: score all s = k+1 draft positions of
@@ -96,6 +114,7 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths, *,
                                        lengths, scale=scale)
 
 
+@_scoped
 def paged_chunk_attention(q, k_pool, v_pool, block_tables, lengths, *,
                           scale: float | None = None):
     """Chunked-prefill attention over pooled KV pages: query j of row r sits
@@ -113,6 +132,7 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, lengths, *,
                                       lengths, scale=scale)
 
 
+@_scoped
 def pq_scan(codes, lut):
     if _use_kernel("pq_scan", True):
         return _pq.pq_scan(codes, lut)

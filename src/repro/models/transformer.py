@@ -180,36 +180,45 @@ def _block_fwd(p, x, positions, cfg: ModelConfig, mode: str, cache, rules,
         # of resharding the nh*hd-wide Q tensor inside attention
         from repro.models.sharding import constrain as _constrain
         h = _constrain(h, rules, ("batch", "attn_qseq", "embed"))
-    if mode == "decode":
-        if cfg.attn_type == "mla":
-            a, new_cache = attn.mla_decode(p["attn"], h, cfg, cache)
+    # named scopes: the compiled program's op_name metadata says which
+    # part of the block each device op belongs to (bench/enginetrace.py)
+    with jax.named_scope("attention"):
+        if mode == "decode":
+            if cfg.attn_type == "mla":
+                a, new_cache = attn.mla_decode(p["attn"], h, cfg, cache)
+            else:
+                a, new_cache = attn.gqa_decode(p["attn"], h, cfg, cache)
+        elif mode == "chunk":
+            if cfg.attn_type == "mla":
+                raise NotImplementedError("chunked prefill supports "
+                                          "gqa-family attention only "
+                                          "(paged KV)")
+            a, new_cache = attn.gqa_prefill_paged(p["attn"], h, cfg, cache,
+                                                  q_valid)
+        elif mode == "verify":
+            if cfg.attn_type == "mla":
+                raise NotImplementedError("speculative verify supports "
+                                          "gqa-family attention only "
+                                          "(paged KV)")
+            a, new_cache = attn.gqa_verify_paged(p["attn"], h, cfg, cache,
+                                                 q_valid)
         else:
-            a, new_cache = attn.gqa_decode(p["attn"], h, cfg, cache)
-    elif mode == "chunk":
-        if cfg.attn_type == "mla":
-            raise NotImplementedError("chunked prefill supports gqa-family "
-                                      "attention only (paged KV)")
-        a, new_cache = attn.gqa_prefill_paged(p["attn"], h, cfg, cache, q_valid)
-    elif mode == "verify":
-        if cfg.attn_type == "mla":
-            raise NotImplementedError("speculative verify supports gqa-family "
-                                      "attention only (paged KV)")
-        a, new_cache = attn.gqa_verify_paged(p["attn"], h, cfg, cache, q_valid)
-    else:
-        if cfg.attn_type == "mla":
-            a, new_cache = attn.mla_prefill(p["attn"], h, positions, cfg,
-                                            cache, rules=rules)
-        else:
-            a, new_cache = attn.gqa_prefill(p["attn"], h, positions, cfg,
-                                            cache, rules=rules)
+            if cfg.attn_type == "mla":
+                a, new_cache = attn.mla_prefill(p["attn"], h, positions, cfg,
+                                                cache, rules=rules)
+            else:
+                a, new_cache = attn.gqa_prefill(p["attn"], h, positions, cfg,
+                                                cache, rules=rules)
     x = x + a
     h = apply_norm(p["ln2"], x, cfg)
     aux = jnp.zeros((), jnp.float32)
     if moe_layer:
-        mo, aux = apply_moe(p["moe"], h, cfg, mesh)
+        with jax.named_scope("moe"):
+            mo, aux = apply_moe(p["moe"], h, cfg, mesh)
         x = x + mo
     else:
-        x = x + apply_mlp(p["mlp"], h, cfg)
+        with jax.named_scope("mlp"):
+            x = x + apply_mlp(p["mlp"], h, cfg)
     if rules is not None:
         x = constrain(x, rules, ("batch", "seq", "embed"))
     return x, new_cache, aux
@@ -452,8 +461,10 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
         # 0 and produce garbage the caller ignores)
         idx = jnp.maximum(q_valid - 1, 0).astype(jnp.int32)[:, None, None]
         x = jnp.take_along_axis(x, idx, axis=1)
-    logits = (x @ head.astype(x.dtype)).astype(jnp.dtype(cfg.logits_dtype))
-    logits = softcap(logits, cfg.logits_softcap)
+    with jax.named_scope("lm_head"):
+        logits = (x @ head.astype(x.dtype)).astype(
+            jnp.dtype(cfg.logits_dtype))
+        logits = softcap(logits, cfg.logits_softcap)
     if mode in ("prefill", "decode", "chunk"):
         logits = logits[:, -1, :]
     return logits, new_caches, aux_total
