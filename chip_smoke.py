@@ -18,9 +18,9 @@ raises and the script exits non-zero without printing a result.
       two paths' first-token logits must agree within ``LOGIT_RTOL``
       (relative L2 per request).
   (d) report: dispatch record (the served path must take the Pallas kernel
-      for flash and paged decode; only ``paged_chunk_attention`` has no
-      kernel), device peak bytes in use, compile and wall seconds. These
-      are smoke numbers, not measurements.
+      for flash, paged decode and paged chunk attention), device peak
+      bytes in use, compile and wall seconds. These are smoke numbers, not
+      measurements.
 
 ``--four-chips`` runs only the disaggregated path: 2 prefill + 2 decode
 workers, one per chip, against the single-chip ``oracle_engine``. Each
@@ -51,8 +51,8 @@ MAX_BATCH = 8
 MAX_LEN = 1024
 CHUNK = 128
 KERNEL_ATOL = 3e-2      # bf16 inputs/outputs, O(1) attention outputs
-# whole (flash kernel) vs chunked (jnp reference) first-token logits: each
-# of 18 layers adds a bf16 rounding difference of ~2**-8 relative, which
+# whole (flash kernel) vs chunked (paged chunk kernel) first-token logits:
+# each of 18 layers adds a bf16 rounding difference of ~2**-8 relative, which
 # random-walks to ~sqrt(18) * 2**-8 = 1.7e-2; allow three times that. A
 # masking or layout fault moves the logits by O(1).
 LOGIT_RTOL = 5e-2
@@ -89,11 +89,12 @@ def kernel_parity(jax, jnp):
     from repro.configs import get_config
     from repro.kernels import ref
     from repro.kernels.flash_attention import flash_attention
-    from repro.kernels.paged_attention import (paged_decode_attention,
+    from repro.kernels.paged_attention import (paged_chunk_attention,
+                                               paged_decode_attention,
                                                paged_verify_attention)
     cfg = get_config(ARCH)
     nh, kvh, d = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    b, bt, mb, s_ver = 8, 16, 64, 5
+    b, bt, mb, s_ver, s_chunk = 8, 16, 64, 5, CHUNK
     nb = b * mb + 1
     key = jax.random.PRNGKey(0)
 
@@ -106,6 +107,9 @@ def kernel_parity(jax, jnp):
                                  nb)[:b * mb].reshape(b, mb).astype(jnp.int32)
     lens = jax.random.randint(jax.random.fold_in(key, 4), (b,), 1,
                               mb * bt - s_ver + 1).astype(jnp.int32)
+    # chunk rows: every position valid, each extent inside the table
+    chunk_lens = jnp.minimum(lens, mb * bt - s_chunk)
+    full = jnp.full((b,), s_chunk, jnp.int32)
     # name -> (kernel, oracle, bf16 operands, int operands); flash is causal
     cases = {
         "paged_decode_attention": (
@@ -114,6 +118,10 @@ def kernel_parity(jax, jnp):
         "paged_verify_attention": (
             paged_verify_attention, ref.paged_verify_attention,
             (rnd(6, (b, s_ver, nh, d)), kp, vp), (tab, lens)),
+        "paged_chunk_attention": (
+            paged_chunk_attention,
+            lambda q, k, v, t, n, _: ref.paged_chunk_attention(q, k, v, t, n),
+            (rnd(10, (b, s_chunk, nh, d)), kp, vp), (tab, chunk_lens, full)),
     }
     for s in (256, 200):          # whole tiles, and a prompt padded to them
         cases[f"flash_attention_s{s}"] = (
@@ -151,7 +159,7 @@ def serve_both():
                 f"{name}: request {r.rid} first-token logits not finite"
         disp = res["dispatch"]
         fell_back = [op for op, impls in disp.items()
-                     if "ref" in impls and op != "paged_chunk_attention"]
+                     if "ref" in impls]
         assert not fell_back, f"{name}: ran the reference for {fell_back}"
         log(f"{name}: dispatch {json.dumps(disp)} compile_s "
             f"{res['compile_s']} wall_s {res['wall_s']} tokens "
@@ -160,6 +168,8 @@ def serve_both():
     for name in runs:
         assert "pallas" in runs[name]["dispatch"].get(
             "paged_decode_attention", {}), f"{name}: no paged decode kernel"
+    assert "pallas" in runs["chunked"]["dispatch"].get(
+        "paged_chunk_attention", {}), "chunked: no paged chunk kernel"
     rel_l2 = lambda x, y: float(np.linalg.norm(x - y)  # noqa: E731
                                 / np.linalg.norm(x))
     rel, rel_last = [], []

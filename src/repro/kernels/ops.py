@@ -22,6 +22,7 @@ import functools
 from typing import Dict
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 from repro.kernels import flash_attention as _fa
@@ -116,18 +117,24 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths, *,
 
 @_scoped
 def paged_chunk_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                          scale: float | None = None):
+                          scale: float | None = None, q_valid=None):
     """Chunked-prefill attention over pooled KV pages: query j of row r sits
     at logical position ``lengths[r] + j`` and attends over every pooled
     position ``<= lengths[r] + j`` (cached context + causal chunk self).
 
-    No Pallas lowering yet (recorded as ``ref`` on every backend) — the
-    chunk pass is prefill-shaped (one big matmul per layer, not memory-bound
-    like decode), so the jnp reference compiles to the same XLA fusions as
-    whole prefill. Numerics match ``ref.flash_attention`` bitwise so chunked
-    K/V + logits reproduce the whole-prompt reference prefill exactly.
+    ``q_valid`` (b,) counts each row's valid chunk positions (default: all
+    ``s``). The kernel walks only the pages a live row's extent covers and
+    skips rows with ``q_valid == 0``; outputs at positions ``>= q_valid``
+    are unspecified. The reference ignores ``q_valid`` and scores every
+    row's whole table: its numerics match ``ref.flash_attention`` bitwise,
+    so off the TPU chunked K/V and logits reproduce whole prefill exactly.
     """
-    _use_kernel("paged_chunk_attention", False)
+    if _use_kernel("paged_chunk_attention",
+                   _lane_dims(q.shape[-1], v_pool.shape[-1])):
+        if q_valid is None:
+            q_valid = jnp.full(q.shape[:1], q.shape[1], jnp.int32)
+        return _pa.paged_chunk_attention(q, k_pool, v_pool, block_tables,
+                                         lengths, q_valid, scale=scale)
     return _ref.paged_chunk_attention(q, k_pool, v_pool, block_tables,
                                       lengths, scale=scale)
 

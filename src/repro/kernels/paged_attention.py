@@ -1,4 +1,5 @@
-"""Pallas TPU paged decode-attention kernel (block-table-indexed KV pool).
+"""Pallas TPU paged attention kernels (block-table-indexed KV pool): decode,
+speculative verify and chunked prefill.
 
 The dense decode kernel (``decode_attention.py``) streams a *contiguous*
 ``(b, S)`` cache; this one gathers K/V through a page table instead, so a
@@ -46,6 +47,36 @@ which satisfies the TPU's (8, 128) tiling rule for every ``kvh`` and every
 entirely (``pl.when``); partial tail pages mask per-position. The reference
 oracle (``ref.paged_decode_attention``) gathers the pool into a dense cache
 and reuses the dense oracle, which makes paged-vs-dense parity exact.
+
+Chunked prefill
+---------------
+``paged_chunk_attention(q, k_pool, v_pool, block_tables, lengths, q_valid)``
+
+* ``q``       — ``(b, s, nh, d)``; query ``j`` of row ``r`` sits at logical
+                position ``lengths[r] + j`` and attends over pooled
+                positions ``<= lengths[r] + j`` (the contract of
+                ``ref.paged_chunk_attention``). The chunk's own K/V must
+                already be written into the pools.
+* ``q_valid`` — ``(b,) int32`` valid chunk positions per row. A row with
+                ``q_valid == 0`` issues no DMA and no compute; outputs at
+                positions ``>= q_valid`` are unspecified (zeros where a
+                whole query block is past ``q_valid``).
+* pools, tables and trash-page conventions as above; a live row's table
+  must cover ``lengths + q_valid`` positions.
+
+Grid ``(batch, query blocks)``; each step holds ``tq`` chunk positions of
+every kv head (flattened with the query-head group into ``tq * g`` rows, as
+the verify kernel lays them out) and walks only the pages ``0 ..
+ceil((lengths + min(block end, q_valid)) / block_tokens) - 1``, several
+pages per step, copied from the pools (``memory_space=pl.ANY``) with manual
+async copies into a double buffer. One page of all kv heads is a
+contiguous ``(block_tokens, kvh * d)`` tile of ``_head_view``. Later table
+entries are neither copied nor scored. Scores, the softmax statistics and
+the accumulator are float32, operands cast as the decode kernel casts them;
+masked keys add exactly 0 (their values are zeroed, so stale or NaN bytes
+in a buffer slot past the extent cannot reach a live output). ``tq`` and
+pages per step come from the shapes (``_chunk_tiles``) so that a step fits
+the default scoped VMEM.
 """
 from __future__ import annotations
 
@@ -262,3 +293,211 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths, *,
       qr, *_head_view(k_pool, v_pool))
     return out.reshape(b, kvh, s, g, dv).transpose(0, 2, 1, 3, 4) \
               .reshape(b, s, nh, dv)
+
+
+# Chunked-prefill attention: query rows per kv head and key tokens per grid
+# step, and the VMEM the step's tiles may take (under the 16 MiB default
+# scoped limit of v5e, with room for the compiler's own scratch).
+_CHUNK_ROWS = 512
+_CHUNK_KEYS = 256
+_CHUNK_VMEM = 12 << 20
+
+
+def _chunk_vmem(kvh, rows, d, dv, tk, q_bytes, kv_bytes):
+    """Bytes of VMEM one grid step of ``paged_chunk_attention`` holds."""
+    io = 2 * kvh * rows * (d + dv) * q_bytes        # q and out, two buffers
+    stats = kvh * rows * (2 * 128 + d + dv) * 4     # m, l (lane-padded), q, acc
+    pages = 2 * tk * kvh * (d + dv) * kv_bytes      # K and V, two slots
+    temps = 3 * rows * tk * 4 + tk * (d + dv) * 4   # one head's scores, f32 K/V
+    return io + stats + pages + temps
+
+
+def _chunk_tiles(s, g, kvh, d, dv, bt, max_blocks, q_bytes, kv_bytes):
+    """``(tq, pages)``: query positions per block and table entries per
+    grid step, from the shapes alone. A query block holds ``tq * g`` rows
+    per kv head (the whole chunk when it is small, else a power of two of
+    positions); a step covers ``pages`` whole pages. Until the step fits
+    ``_CHUNK_VMEM``, pages halve down to 128 tokens a step, then the query
+    block, then pages again."""
+    tq = s
+    if s * g > _CHUNK_ROWS:
+        tq = 8
+        while 2 * tq * g <= _CHUNK_ROWS:
+            tq *= 2
+    pages = max(1, min(max_blocks, _CHUNK_KEYS // bt))
+
+    def fits():
+        return _chunk_vmem(kvh, tq * g, d, dv, pages * bt, q_bytes,
+                           kv_bytes) <= _CHUNK_VMEM
+
+    while not fits():
+        if pages > 1 and (pages * bt > 128 or tq <= 8 or tq % 16):
+            pages //= 2
+        elif tq > 8 and tq % 16 == 0:
+            tq //= 2
+        else:
+            break
+    return tq, pages
+
+
+def _page_head(buf, slot, h, width):
+    """Kv head ``h`` of the pages in ``buf[slot]`` as ``(tokens, width)``
+    float32; ``buf`` holds pages as stored, ``(bt, kvh, width)``, or in the
+    head view, ``(bt, kvh * width)``."""
+    if buf.ndim == 5:
+        x = buf[slot, :, :, h, :]
+    else:
+        x = buf[slot, :, :, pl.ds(h * width, width)]
+    return x.astype(jnp.float32).reshape(-1, width)
+
+
+def _paged_chunk_kernel(tab_ref, len_ref, qv_ref, q_ref, k_hbm, v_hbm, o_ref,
+                        k_buf, v_buf, sem, q_scr, m_ref, l_ref, acc_ref, *,
+                        scale: float, kvh: int):
+    """One (row, query block): walk the pages its causal extent covers,
+    ``pages`` at a time, double-buffered, all kv heads per step.
+
+    The block is ``tq`` chunk positions of every query head. Row ``r`` of
+    kv head ``h``'s scratch is position ``r // g``, query head
+    ``h * g + r % g``. A block with no valid query position does no DMA and
+    no compute and writes zeros."""
+    bi, qi = pl.program_id(0), pl.program_id(1)
+    pages, bt = k_buf.shape[1:3]
+    tk = pages * bt
+    tq, nh, d = q_ref.shape[1:]
+    dv = o_ref.shape[-1]
+    g = nh // kvh
+    rows = tq * g
+    length, q_valid = len_ref[bi], qv_ref[bi]
+    q_lo = qi * tq
+    # keys [0, end): the causal extent of the block's last valid position
+    end = length + jnp.minimum(q_lo + tq, q_valid)
+    n_pages = jnp.minimum(pl.cdiv(end, bt), tab_ref.shape[1])
+    n_steps = pl.cdiv(n_pages, pages)
+
+    def for_pages(step, slot, act):
+        """``act`` on the K and V copy of each page of ``step`` inside the
+        extent: pages past it are neither copied nor waited on."""
+        def one(p, carry):
+            page = tab_ref[bi, step * pages + p]
+            act(pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, p],
+                                      sem.at[0, slot]))
+            act(pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, p],
+                                      sem.at[1, slot]))
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(n_pages - step * pages, pages), one,
+                          0)
+
+    @pl.when(q_lo < q_valid)
+    def _run():
+        for_pages(0, 0, lambda c: c.start())
+        for h in range(kvh):
+            q_scr[h] = q_ref[0, :, pl.ds(h * g, g), :].astype(
+                jnp.float32).reshape(rows, d)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # a row sees keys <= its position and < end; padding rows past
+        # q_valid thus see the block's whole extent and stay finite
+        qpos = length + q_lo + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) // g
+        last = jnp.minimum(qpos, end - 1)
+
+        def step_body(step, carry):
+            slot = step % 2
+            for_pages(step, slot, lambda c: c.wait())
+
+            @pl.when(step + 1 < n_steps)
+            def _prefetch():
+                for_pages(step + 1, 1 - slot, lambda c: c.start())
+
+            key = step * tk + jax.lax.broadcasted_iota(jnp.int32, (rows, tk), 1)
+            seen = key <= last
+            # slots past the extent hold stale bytes: zero their values so
+            # that a masked key adds exactly 0 (never 0 * NaN)
+            key_live = (step * tk + jax.lax.broadcasted_iota(
+                jnp.int32, (tk, 1), 0)) < end
+            for h in range(kvh):
+                k = _page_head(k_buf, slot, h, d)
+                v = jnp.where(key_live, _page_head(v_buf, slot, h, dv), 0.0)
+                s = jax.lax.dot_general(
+                    q_scr[h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(seen, s, NEG_INF)
+                m_prev, l_prev = m_ref[h], l_ref[h]               # (rows, 1)
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                l_ref[h] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, n_steps, step_body, 0)
+        for h in range(kvh):
+            out = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, :, pl.ds(h * g, g), :] = out.reshape(tq, g, dv).astype(
+                o_ref.dtype)
+
+    @pl.when(q_lo >= q_valid)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_chunk_attention(q, k_pool, v_pool, block_tables, lengths, q_valid,
+                          *, scale: float | None = None,
+                          interpret: bool = False):
+    """Chunked-prefill attention over the paged pool; see the module
+    docstring for the contract. Returns ``(b, s, nh, dv)``."""
+    b, s, nh, d = q.shape
+    bt, kvh = k_pool.shape[1], k_pool.shape[2]
+    g = nh // kvh
+    dv = v_pool.shape[-1]
+    max_blocks = block_tables.shape[1]
+    scale = d ** -0.5 if scale is None else scale
+    tq, pages = _chunk_tiles(s, g, kvh, d, dv, bt, max_blocks,
+                             q.dtype.itemsize, k_pool.dtype.itemsize)
+    nq = pl.cdiv(s, tq)
+    if nq * tq != s:
+        q = jnp.pad(q, ((0, 0), (0, nq * tq - s), (0, 0), (0, 0)))
+    # Copy pages as stored, (bt, kvh, d), where the kv heads fill whole
+    # packed sublanes: the head view would cost a relayout of both pools.
+    # Odd kv heads of a packed dtype (a single one, as in MQA) pad their
+    # sublane tile, which a page copy cannot slice: they take the head view.
+    pools = (k_pool, v_pool)
+    if kvh % (4 // k_pool.dtype.itemsize) or kvh == 1:
+        pools = _head_view(k_pool, v_pool)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,          # block_tables, lengths, q_valid
+        grid=(b, nq),
+        in_specs=[
+            pl.BlockSpec((1, tq, nh, d), lambda bi, qi, *_: (bi, qi, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, tq, nh, dv),
+                               lambda bi, qi, *_: (bi, qi, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages) + pools[0].shape[1:], k_pool.dtype),
+            pltpu.VMEM((2, pages) + pools[1].shape[1:], v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((kvh, tq * g, d), jnp.float32),
+            pltpu.VMEM((kvh, tq * g, 1), jnp.float32),
+            pltpu.VMEM((kvh, tq * g, 1), jnp.float32),
+            pltpu.VMEM((kvh, tq * g, dv), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_chunk_kernel, scale=scale, kvh=kvh),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, nq * tq, nh, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+        ),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      q_valid.astype(jnp.int32), q, *pools)
+    return out[:, :s]

@@ -224,9 +224,11 @@ def gqa_prefill_paged(params, x, cfg: ModelConfig, cache: Dict,
     identically (aliasing dedups memory, not compute), so concurrent
     readers of those pages are unperturbed.
 
-    Numerics match whole-prompt ``gqa_prefill`` bitwise: same einsum/rope
-    recipe per position, and the chunk attention mirrors
-    ``flash_attention``'s fp32 path with exact-zero masked tails.
+    Off the TPU, numerics match whole-prompt ``gqa_prefill`` bitwise: same
+    einsum/rope recipe per position, and the chunk attention reference
+    mirrors ``flash_attention``'s fp32 path with exact-zero masked tails.
+    On the TPU the Pallas chunk kernel walks only the pages each row with
+    ``q_valid > 0`` covers.
     """
     from repro.kernels import ops
 
@@ -259,7 +261,7 @@ def gqa_prefill_paged(params, x, cfg: ModelConfig, cache: Dict,
     k_pool = upd(k_pool, k)
     v_pool = upd(v_pool, v)
     out = ops.paged_chunk_attention(q, k_pool, v_pool, tables, lengths,
-                                    scale=hd ** -0.5)
+                                    scale=hd ** -0.5, q_valid=q_valid)
     out = jnp.einsum("bsnh,nhd->bsd", out, params["wo"])
     return out, {"k_pool": k_pool, "v_pool": v_pool, "block_tables": tables,
                  "length": lengths + q_valid}

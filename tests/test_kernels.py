@@ -8,6 +8,7 @@ import pytest
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.paged_attention import paged_chunk_attention
 from repro.kernels.pq_scan import pq_scan
 
 KEY = jax.random.PRNGKey(0)
@@ -111,11 +112,56 @@ def test_kernels_lane_width_heads(kvh):
                                want.astype(np.float32), atol=3e-2, rtol=3e-2)
 
 
+# Chunk rows as (length, q_valid) on a 24-page table of 16-token pages
+# (384 positions), 64 chunk positions a row.
+CHUNK_ROWS = {
+    # a first chunk, rows riding along with nothing to write, a short chunk
+    "fresh_idle_short": [(0, 64), (200, 0), (0, 5), (37, 0)],
+    # starts mid-page and on a page edge; extents of 4 to 20 pages
+    "mid_page_and_edge": [(7, 64), (32, 40), (120, 33), (300, 10)],
+    # extents that end exactly at the table's end
+    "table_end": [(320, 64), (383, 1), (352, 32), (0, 0)],
+}
+
+
+@pytest.mark.parametrize("rows", sorted(CHUNK_ROWS))
+@pytest.mark.parametrize("nh,kvh,d", [(8, 1, 128), (8, 1, 256),
+                                      (48, 8, 128), (48, 8, 256)])
+def test_paged_chunk_attention_matches_ref(nh, kvh, d, rows):
+    """The chunk kernel equals the reference at every valid position of
+    every live row, with NaN in the trash page, in every page no live
+    extent covers and past each extent in its last page: it reads only
+    what the live rows' extents hold."""
+    b, s, bt, mb = 4, 64, 16, 24
+    nb = b * mb + 1                                 # the last is the trash
+    lengths = jnp.array([n for n, _ in CHUNK_ROWS[rows]], jnp.int32)
+    q_valid = jnp.array([v for _, v in CHUNK_ROWS[rows]], jnp.int32)
+    q, _, _ = _qkv(b, s, nh, kvh, d)
+    kp = jax.random.normal(jax.random.fold_in(KEY, 7), (nb, bt, kvh, d))
+    vp = jax.random.normal(jax.random.fold_in(KEY, 8), (nb, bt, kvh, d))
+    tab = jax.random.permutation(jax.random.fold_in(KEY, 9),
+                                 nb - 1).reshape(b, mb).astype(jnp.int32)
+    want = ref.paged_chunk_attention(q, kp, vp, tab, lengths)
+
+    seen = np.zeros((nb, bt), bool)                 # positions live rows own
+    for (length, valid), pages in zip(CHUNK_ROWS[rows], np.asarray(tab)):
+        for p in range(length + valid if valid else 0):
+            seen[pages[p // bt], p % bt] = True
+    dirty = jnp.asarray(~seen)[:, :, None, None]
+    got = paged_chunk_attention(q, jnp.where(dirty, jnp.nan, kp),
+                                jnp.where(dirty, jnp.nan, vp), tab, lengths,
+                                q_valid, interpret=True)
+    for r in range(b):
+        n = int(q_valid[r])
+        np.testing.assert_allclose(got[r, :n], want[r, :n], atol=2e-5,
+                                   rtol=2e-5)
+
+
 def test_ops_dispatch_rules_and_record(monkeypatch):
     """Off the TPU every op takes the reference; on it (steered here) the
-    kernel runs exactly when the head dims are whole 128-lane tiles, and
-    ``paged_chunk_attention`` always records the reference. Choices are
-    counted per trace (fresh lambdas, so no trace is served from a cache)."""
+    kernel runs exactly when the head dims are whole 128-lane tiles. Choices
+    are counted per trace (fresh lambdas, so no trace is served from a
+    cache)."""
     def trace_all():
         ops.DISPATCH.clear()
         for d in (64, 128):
@@ -135,5 +181,5 @@ def test_ops_dispatch_rules_and_record(monkeypatch):
     monkeypatch.setattr(ops, "_platform", lambda: "tpu")
     assert trace_all() == {
         "flash_attention": {"pallas": 1, "ref": 1},
-        "paged_chunk_attention": {"ref": 2},
+        "paged_chunk_attention": {"pallas": 1, "ref": 1},
         "paged_decode_attention": {"pallas": 1, "ref": 1}}
