@@ -55,7 +55,7 @@ def readings(spec: dict, seed: int, seconds: float, fault: str = "") -> dict:
         gc.collect()
     finally:
         planted.undo()
-    ref = reference.Reference(spec["config"], seed)
+    ref = spec["arch"].Reference(spec["config"], seed)
     prog, ctrl = 0.0, 0.0
     for r in pick:
         prog = max(prog, float(reference.gaps(ref, r.prompt, r.tokens).max()))

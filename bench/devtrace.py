@@ -203,7 +203,7 @@ class Context:
     reduction: Reduction
     feeder: object
     window: object
-    shape: object
+    shape: object            # the architecture's Shape (bench/archs/)
     peak: dict
     chips: int
     spec: dict

@@ -1,8 +1,9 @@
 """step_mfu (whole step): useful model FLOPs over the chip's peak, percent.
 
 The FLOPs of the real prompt tokens written and the output tokens streamed
-in the traced window (``roofline.span_flops``, ``roofline.token_flops``;
-padded chunk positions and recompute re-prefills are not useful work),
+in the traced window (the architecture's ``Shape.span_flops``,
+``token_flops`` and ``head_flops``, counted by ``serve.Feeder``; padded
+chunk positions and recompute re-prefills are not useful work),
 over the traced window's length times chips times peak bf16 FLOP/s."""
 
 

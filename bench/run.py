@@ -4,10 +4,12 @@
     python3 bench/run.py --workload internlm2.chat --seed 7 --seconds 51 --trace 0
 
 Reads ``BENCHMARK.json`` at the checkout's root and finds everything else
-by name: the configuration's file (``configs`` entry), the traffic mix
-(``bench/traffic/<traffic>.json``), the cell's engine settings, load and
-check limits (``bench/cells/<workload>.json``) and, with ``--trace 1``, one
-reader per per-layer metric (``bench/metrics/<name>.py``).
+by name: the configuration's file (``configs`` entry), the module of the
+architecture that file names (``bench/archs/<arch>.py``: the program's model
+config, the plain reference, the counts and the ops that must take Pallas),
+the traffic mix (``bench/traffic/<traffic>.json``), the cell's engine
+settings, load and check limits (``bench/cells/<workload>.json``) and, with
+``--trace 1``, one reader per per-layer metric (``bench/metrics/<name>.py``).
 
 A run: check the device (a TPU with enough chips, else exit 2 with no
 result); make the weights from the seed in one jitted call; build the paged
@@ -15,7 +17,7 @@ result); make the weights from the seed in one jitted call; build the paged
 the measured window of ``--seconds``, then drain (serve on, arrivals
 continuing, until every request due in the window has finished); read the
 device's peak memory; free the engine; compare a sample of the requests due
-in the window with the plain reference (``reference.py``); print the checks
+in the window with the architecture's plain reference; print the checks
 on standard error and one JSON line on standard output. ``setup_s`` runs
 from process start to the window's start (weights, compile or cache load,
 warm-up, lead-in).
@@ -44,7 +46,7 @@ sys.path.insert(0, BENCH)
 
 import reference                              # noqa: E402
 import traffic                                # noqa: E402
-from roofline import Shape, peaks             # noqa: E402
+from roofline import peaks                    # noqa: E402
 from serve import Feeder, Window, percentile  # noqa: E402
 
 WARMUP_NEW_TOKENS = 3
@@ -71,10 +73,11 @@ def cell_spec(workload: str, root: str = ROOT) -> dict:
                          f"{sorted(cells)}")
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
-    conf_entry = configs[w["config"]]
+    config = load_json(os.path.join(root, configs[w["config"]]["file"]))
     return {
         "workload": w,
-        "config": load_json(os.path.join(root, conf_entry["file"])),
+        "config": config,
+        "arch": arch_module(config, root),
         "mix": traffic.load_mix(w["traffic"], os.path.join(root, "bench")),
         "cell": load_json(os.path.join(root, "bench", "cells",
                                        f"{workload}.json")),
@@ -85,33 +88,34 @@ def cell_spec(workload: str, root: str = ROOT) -> dict:
     }
 
 
+def _load(name: str, path: str):
+    """The module at ``path``, executed afresh under ``name`` (registered,
+    as a dataclass in it needs its module while it is made)."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str, root: str = ROOT):
     path = os.path.join(root, "bench", "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(f"bench_metric_{name}", path).read
 
 
-def model_config(config: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig
-    if config.get("hidden_act") != "silu":
-        raise ValueError("only SwiGLU (silu) decoders are wired here")
-    return ModelConfig(
-        name=config["name"], family="dense",
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config.get("head_dim") or 0,
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        mlp_type="swiglu", attn_type="gqa",
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        param_dtype="bfloat16", compute_dtype="bfloat16",
-        logits_dtype="float32")
+def arch_module(config: dict, root: str = ROOT):
+    """The module of the architecture the configuration names under
+    ``arch``: ``bench/archs/<arch>.py``. There is no default."""
+    arch = config.get("arch")
+    if not (isinstance(arch, str) and arch.isidentifier()):
+        raise SystemExit(f"configuration {config.get('name')!r} names no "
+                         f"\"arch\" (it has {arch!r}); it has to name its "
+                         f"architecture's module, bench/archs/<arch>.py")
+    path = os.path.join(root, "bench", "archs", f"{arch}.py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"configuration {config.get('name')!r} names arch "
+                         f"{arch!r}, but bench/archs/{arch}.py does not exist")
+    return _load(f"bench_arch_{arch}", path)
 
 
 # ---------------------------------------------------------------------------
@@ -172,7 +176,7 @@ def build_engine(spec: dict, seed: int):
     import jax
     from repro.engine.core import Engine, EngineConfig
     from repro.models import transformer as tf
-    cfg = model_config(spec["config"])
+    cfg = spec["arch"].model_config(spec["config"])
     e = spec["cell"]["engine"]
     params = tf.init_params(cfg, reference.seed_key(seed), False)
     jax.block_until_ready(params)
@@ -206,7 +210,8 @@ def serve(eng, spec: dict, seed: int, annotate, seconds: float):
     a window of whole blocks holds exactly those blocks' requests."""
     cell, mix = spec["cell"], spec["mix"]
     load = cell["load"]
-    shape = Shape.from_config(spec["config"], cell["engine"]["block_tokens"])
+    shape = spec["arch"].Shape.from_config(spec["config"],
+                                           cell["engine"]["block_tokens"])
     block = load.get("block", 0)
     arrivals = traffic.requests(mix, seed, spec["config"]["vocab_size"], block)
     feed = Feeder(eng, arrivals, shape, load["rate_per_s"], annotate=annotate)
@@ -255,7 +260,7 @@ def check_outputs(spec: dict, seed: int, pick: list) -> dict:
     served = sum(len(r.tokens) for r in pick)
     if not pick:
         return {"logit_gap_max": None, "served_tokens": 0}
-    ref = reference.Reference(spec["config"], seed)
+    ref = spec["arch"].Reference(spec["config"], seed)
     worst = 0.0
     for r in pick:
         g = reference.gaps(ref, r.prompt, r.tokens)
@@ -286,10 +291,11 @@ def run(args) -> int:
     log(f"warm-up {time.monotonic() - t:.3f} s")
     dispatch = ops.dispatch_record()
     log(f"dispatch of the window's programs {dispatch}")
-    if device["platform"] == "tpu" and \
-            set(dispatch.get("paged_decode_attention", {})) != {"pallas"}:
-        raise RuntimeError("the decode pass does not take the Pallas paged "
-                           f"kernel: {dispatch}")
+    slow = [op for op in spec["arch"].PALLAS_OPS
+            if set(dispatch.get(op, {})) != {"pallas"}]
+    if device["platform"] == "tpu" and slow:
+        raise RuntimeError(f"{slow} do not take their Pallas kernels: "
+                           f"{dispatch}")
 
     tracing = bool(args.trace)
     annotate = ((lambda name: jax.profiler.TraceAnnotation(name)) if tracing

@@ -12,7 +12,8 @@ Every request is timed from its due time, not from when ``submit`` ran.
 The feeder counts what the window did from public request state: the model
 FLOPs of prompt tokens written into the pool (``prefilled``, its high-water
 mark, so a recompute resume is not counted again) and of tokens streamed
-(``tokens``), and preemptions (``preemptions``).
+(``tokens``), as the architecture's ``Shape`` counts them
+(``bench/archs/<arch>.py``), and preemptions (``preemptions``).
 """
 from __future__ import annotations
 
@@ -22,8 +23,6 @@ from dataclasses import dataclass
 from typing import Iterator, List, Optional
 
 import numpy as np
-
-from roofline import Shape, span_flops, token_flops
 
 
 @dataclass
@@ -58,7 +57,7 @@ class Window:
 
 
 class Feeder:
-    def __init__(self, eng, arrivals: Iterator, shape: Shape, rate: float,
+    def __init__(self, eng, arrivals: Iterator, shape, rate: float,
                  annotate=None):
         self.eng = eng
         self.arrivals = arrivals
@@ -115,14 +114,13 @@ class Feeder:
                 t.prefilled = r.prefilled
                 hw = min(r.prefilled, t.prompt_len)
                 if hw > t.written:
-                    it.flops += span_flops(self.shape, t.written, hw)
+                    it.flops += self.shape.span_flops(t.written, hw)
                     t.written = hw
                 n = len(r.tokens)
                 for k in range(t.streamed, n):
-                    it.flops += (2 * self.shape.hidden * self.shape.vocab
-                                 if k == 0 else
-                                 token_flops(self.shape, t.prompt_len + k - 1,
-                                             True))
+                    it.flops += (self.shape.head_flops() if k == 0 else
+                                 self.shape.token_flops(t.prompt_len + k - 1,
+                                                        True))
                 t.streamed = n
                 if r.preemptions > t.preemptions:
                     it.preemptions += r.preemptions - t.preemptions

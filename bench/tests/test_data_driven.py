@@ -1,20 +1,50 @@
-"""A later change adds a cell, a traffic mix or a per-layer metric by adding
-files and BENCHMARK.json entries only: the harness finds them by name."""
+"""A later change adds a cell, a traffic mix, a per-layer metric or an
+architecture by adding files and BENCHMARK.json entries only: the harness
+finds them by name."""
+import hashlib
 import json
 import os
-import shutil
 
 import run
-from conftest import ROOT, small_spec
+from conftest import copy_checkout, small_spec
+
+# an architecture added as a new file: the dense decoder under another name
+TOY_ARCH = '''"""The dense GQA decoder under another name."""
+import importlib.util
+import os
+import sys
+
+_spec = importlib.util.spec_from_file_location(
+    "toy_dense_gqa",
+    os.path.join(os.path.dirname(os.path.abspath(__file__)), "dense_gqa.py"))
+_base = sys.modules["toy_dense_gqa"] = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_base)
+model_config = _base.model_config
+Reference = _base.Reference
+Shape = _base.Shape
+PALLAS_OPS = _base.PALLAS_OPS
+SMALL = _base.SMALL
+'''
 
 
-def _copy_checkout(tmp_path):
-    root = tmp_path / "checkout"
-    root.mkdir()
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "bench"), root / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    return root
+def _hashes(root):
+    out = {}
+    for d, dirs, files in os.walk(root / "bench"):
+        dirs[:] = [x for x in dirs if x != "__pycache__"]
+        for f in files:
+            path = os.path.join(d, f)
+            with open(path, "rb") as fh:
+                out[path] = hashlib.sha256(fh.read()).hexdigest()
+    return out
+
+
+def _add_entries(root, config=None, workload=None, per_layer=None):
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for key, entry in (("configs", config), ("workloads", workload),
+                       ("per_layer", per_layer)):
+        if entry is not None:
+            bench[key].append(entry)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
 
 def _add_burst_cell(root):
@@ -30,20 +60,50 @@ def _add_burst_cell(root):
         json.dumps(cell))
     (root / "bench" / "metrics" / "queue_wait_ms.py").write_text(
         "def read(ctx):\n    return 1.5\n")
-    bench = json.loads((root / "BENCHMARK.json").read_text())
-    bench["workloads"].append({
+    _add_entries(root, workload={
         "name": "internlm2.chat-burst", "config": "internlm2-20b.s8",
         "traffic": "azure-conv-burst", "chips": 1,
-        "why": "cell 1 with bursty arrivals"})
-    bench["per_layer"].append({
+        "why": "cell 1 with bursty arrivals"}, per_layer={
         "name": "queue_wait_ms", "unit": "ms", "better": "lower",
         "source": "program_counter", "layer": "engine", "moves": "itl_p95_s",
         "workloads": ["internlm2.chat-burst"]})
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+def _add_toy_arch_cell(root):
+    (root / "bench" / "archs" / "toy.py").write_text(TOY_ARCH)
+    config = json.loads((root / "bench" / "configs" /
+                         "internlm2-20b.s8.json").read_text())
+    config.update(name="toy-20b.s8", arch="toy")
+    (root / "bench" / "configs" / "toy-20b.s8.json").write_text(
+        json.dumps(config))
+    (root / "bench" / "cells" / "toy.chat.json").write_text(
+        (root / "bench" / "cells" / "internlm2.chat.json").read_text())
+    _add_entries(root, config={
+        "name": "toy-20b.s8", "source": "https://huggingface.co/org/toy",
+        "file": "bench/configs/toy-20b.s8.json",
+        "reduced": ["num_hidden_layers"], "why": "a second architecture"},
+        workload={"name": "toy.chat", "config": "toy-20b.s8",
+                  "traffic": "azure-conv", "chips": 1,
+                  "why": "the toy architecture under chat traffic"})
+
+
+def _rehearse_in(root, workload, monkeypatch, capsys):
+    """``run.run`` of ``workload`` in the checkout at ``root``, cut to the
+    CPU's size, with the look for a chip skipped."""
+    real = run.cell_spec
+    monkeypatch.setattr(run, "cell_spec", lambda name, root_=None:
+                        small_spec(real(name, str(root))))
+    monkeypatch.setattr(run, "use_cache", lambda jax: None)
+    monkeypatch.setattr(run, "require_chip", lambda jax, chips: {
+        "platform": "cpu", "kind": "rehearsal", "count": chips})
+    args = run.parse(["--workload", workload, "--seed", "9",
+                      "--seconds", "2", "--trace", "0"])
+    assert run.run(args) == 0
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
 
 def test_new_files_are_found_by_name(tmp_path):
-    root = _copy_checkout(tmp_path)
+    root = copy_checkout(tmp_path)
     before = {p: (root / "bench" / p).read_bytes()
               for p in ("traffic/azure-conv.json", "cells/internlm2.chat.json")}
     _add_burst_cell(root)
@@ -60,17 +120,27 @@ def test_new_files_are_found_by_name(tmp_path):
 
 
 def test_new_cell_rehearses(tmp_path, monkeypatch, capsys):
-    root = _copy_checkout(tmp_path)
+    root = copy_checkout(tmp_path)
     _add_burst_cell(root)
-    real = run.cell_spec
-    monkeypatch.setattr(run, "cell_spec", lambda name, root_=None:
-                        small_spec(real(name, str(root))))
-    monkeypatch.setattr(run, "use_cache", lambda jax: None)
-    monkeypatch.setattr(run, "require_chip", lambda jax, chips: {
-        "platform": "cpu", "kind": "rehearsal", "count": chips})
-    args = run.parse(["--workload", "internlm2.chat-burst", "--seed", "9",
-                      "--seconds", "2", "--trace", "0"])
-    assert run.run(args) == 0
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    line = _rehearse_in(root, "internlm2.chat-burst", monkeypatch, capsys)
     assert line["correct"] is True
     assert set(line["metrics"]) == {"itl_p95_s", "setup_s"}
+
+
+def test_new_architecture_is_new_files_only(tmp_path, monkeypatch, capsys):
+    """An architecture module, a configuration that names it, a cell and
+    the BENCHMARK.json entries: the cell rehearses end to end through the
+    new module, and no file that was there changed."""
+    root = copy_checkout(tmp_path)
+    before = _hashes(root)
+    _add_toy_arch_cell(root)
+    spec = run.cell_spec("toy.chat", root=str(root))
+    assert spec["arch"].__file__ == str(root / "bench" / "archs" / "toy.py")
+    line = _rehearse_in(root, "toy.chat", monkeypatch, capsys)
+    assert line["correct"] is True
+    assert set(line["metrics"]) == {"itl_p95_s", "setup_s"}
+    after = _hashes(root)
+    assert {p: after[p] for p in before} == before
+    assert set(after) - set(before) == {
+        str(root / "bench" / p) for p in (
+            "archs/toy.py", "configs/toy-20b.s8.json", "cells/toy.chat.json")}

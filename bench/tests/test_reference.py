@@ -1,23 +1,25 @@
 """The plain reference, held to the served model on the CPU at a small size.
 
-The reference makes its weights again from the seed; they must be the
-served model's own, bit for bit. Its float32 logits must then agree with the
-served model's forward pass to within bfloat16 rounding."""
+Each cell's reference is its architecture's (``bench/archs/<arch>.py``). It
+makes its weights again from the seed; they must be the served model's own,
+bit for bit. Its float32 logits must then agree with the served model's
+forward pass to within bfloat16 rounding."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 import reference
 import run
-from conftest import SMALL_CONFIG, benchmark, small_spec
+from conftest import benchmark, small_spec
 from repro.models import transformer as tf
 
 SEED = 2 ** 32 + 2 ** 31 + 3       # seeds may run past 32 bits
 
 
-def small_config():
+def small_cell():
+    """The first cell's architecture and its configuration at CPU size."""
     spec = small_spec(run.cell_spec(benchmark()["workloads"][0]["name"]))
-    return spec["config"]
+    return spec["arch"], spec["config"]
 
 
 def test_seed_key_keeps_every_bit():
@@ -27,10 +29,10 @@ def test_seed_key_keeps_every_bit():
 
 
 def test_weights_are_the_served_models_bit_for_bit():
-    config = small_config()
-    params = tf.init_params(run.model_config(config),
+    arch, config = small_cell()
+    params = tf.init_params(arch.model_config(config),
                             reference.seed_key(SEED), False)
-    ref = reference.Reference(config, SEED)
+    ref = arch.Reference(config, SEED)
     np.testing.assert_array_equal(np.asarray(params["embed"]),
                                   np.asarray(ref.outer["embed"]))
     np.testing.assert_array_equal(np.asarray(params["head"]),
@@ -50,12 +52,12 @@ def test_weights_are_the_served_models_bit_for_bit():
 
 
 def test_logits_agree_with_the_served_forward_pass():
-    config = small_config()
-    cfg = run.model_config(config)
+    arch, config = small_cell()
+    cfg = arch.model_config(config)
     params = tf.init_params(cfg, reference.seed_key(SEED), False)
-    ref = reference.Reference(config, SEED)
+    ref = arch.Reference(config, SEED)
     rng = np.random.default_rng(0)
-    toks = rng.integers(0, SMALL_CONFIG["vocab_size"], 70).astype(np.int32)
+    toks = rng.integers(0, config["vocab_size"], 70).astype(np.int32)
     served, _, _ = tf.forward(params, cfg, tokens=jnp.asarray(toks[None]),
                               mode="train")
     rows = np.arange(len(toks))
@@ -67,10 +69,10 @@ def test_logits_agree_with_the_served_forward_pass():
 
 
 def test_control_moves_the_logits_more_than_bf16():
-    config = small_config()
-    ref = reference.Reference(config, SEED)
+    arch, config = small_cell()
+    ref = arch.Reference(config, SEED)
     rng = np.random.default_rng(1)
-    toks = rng.integers(0, SMALL_CONFIG["vocab_size"], 70).astype(np.int32)
+    toks = rng.integers(0, config["vocab_size"], 70).astype(np.int32)
     rows = np.arange(len(toks))
     exact = ref.logits(toks, rows)
     fp8 = ref.logits(toks, rows, control=True)

@@ -5,16 +5,14 @@ shows that the harness drives the engine, counts, checks and prints a
 well-formed line; its numbers are CPU numbers and are never reported.
 """
 import json
-import os
 
-import jax
 import pytest
 
 import devtrace
-import roofline
 import run
 
-from conftest import workload_names
+from conftest import (WITH_SPANS, WITH_SPANS_SCOPES, WITHOUT_SPANS, traced,
+                      workload_names)
 
 
 @pytest.mark.parametrize("workload", workload_names())
@@ -34,27 +32,11 @@ def test_cell_runs_end_to_end(rehearse, capsys, workload):
     assert "check logit_gap_max" in out.err.strip().splitlines()[-1]
 
 
-RECORDED = os.path.join(os.path.dirname(__file__), "data",
-                        "internlm2.chat.xplane.pb.gz")
-RECORDED_ON = "TPU v5 lite"
-
-
-def traced(reduce):
-    """Run with ``--trace 1`` on the CPU: the profiler is left off and the
-    reduction is ``reduce()``, made from the trace recorded on the chip, so
-    every per-layer reader runs on the feeder's own iterations, against the
-    peaks of the chip the trace was recorded on."""
-    def patch(monkeypatch):
-        monkeypatch.setattr(run, "peaks", lambda kind: roofline.peaks(RECORDED_ON))
-        monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
-        monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
-        monkeypatch.setattr(devtrace, "reduce_dir", lambda d: reduce())
-    return patch
-
-
 @pytest.mark.parametrize("workload", workload_names())
 def test_traced_run_reads_every_per_layer_metric(rehearse, capsys, workload):
-    patch = traced(lambda: devtrace.reduce_file(RECORDED))
+    with open(WITH_SPANS_SCOPES) as f:
+        scopes = json.load(f)
+    patch = traced(WITH_SPANS, scopes)
     assert rehearse(workload, seconds=2.0, trace=1, patch=patch) == 0
     out = capsys.readouterr()
     line = json.loads(out.out.strip().splitlines()[-1])
@@ -73,9 +55,11 @@ def test_traced_run_without_the_chunk_program_fails_loudly(rehearse,
     """The feeder sees the window's chunk passes, so a trace without the
     chunk program is a renamed program, not a zero."""
     def reduce():
-        red = devtrace.reduce_file(RECORDED)
+        red = devtrace.reduce_file(WITHOUT_SPANS)
         red.modules = [m for m in red.modules
                        if not devtrace.PROGRAMS["chunk"].search(m[0])]
         return red
     with pytest.raises(LookupError, match="chunk"):
-        rehearse(workload, seconds=2.0, trace=1, patch=traced(reduce))
+        rehearse(workload, seconds=2.0, trace=1,
+                 patch=traced(WITHOUT_SPANS, {"decode": {}, "chunk": {}},
+                              reduce))
